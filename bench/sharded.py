@@ -12,8 +12,8 @@ throughput. One JSON row per (algo, engine), bench.py-style.
 The ``pipeline`` family separates COMPUTE time from EXPOSED-COMM time
 per engine: the compute baseline is the identical per-shard scan on a
 single-device mesh over one shard's rows (no collective in the
-program), fenced exactly like the full-mesh runs (the PR 11
-block-until-ready protocol), and ``exposed_comm_ms = total −
+program), timed exactly like the full-mesh runs (each round ends in
+``block_until_ready``), and ``exposed_comm_ms = total −
 compute`` — so "exchange hidden at 4+ shards" is a measured number per
 engine, not a claim. Rows: ``sharded_pipeline_ms`` with
 ``phase=total|compute|exposed_comm`` per engine.
@@ -39,23 +39,23 @@ def _emit(metric, value, unit, _nd: int = 1, **extra):
 
 
 def _qps(fn, q, reps, rounds):
-    """Pipelined eager dispatch + one fence per round, RTT-corrected —
+    """Pipelined eager dispatch, one ``block_until_ready`` per round —
     the bench.py _eager_qps protocol (sharded searches are eager calls
     around a jitted shard_map)."""
     return q.shape[0] / _sec_per_call(fn, q, reps, rounds)
 
 
 def _sec_per_call(fn, q, reps, rounds):
-    from bench.common import fence, link_rtt
+    import jax
 
-    fence(fn(q))  # compile + warm
+    jax.block_until_ready(fn(q))  # compile + warm
     times = []
     for _ in range(rounds):
         t0 = time.perf_counter()
         for _ in range(reps):
             out = fn(q)
-        fence(out)
-        times.append((time.perf_counter() - t0 - link_rtt()) / reps)
+        jax.block_until_ready(out)
+        times.append((time.perf_counter() - t0) / reps)
     return float(np.median(times))
 
 
@@ -109,7 +109,7 @@ def run(quick: bool = False) -> None:
     # ---- pipeline family (ISSUE 14): compute vs exposed-comm per engine.
     # Compute baseline: the IDENTICAL per-shard scan volume on a
     # 1-device mesh (one shard's rows, same model shape / n_probes / k)
-    # — a compiled program with NO collective, fenced by the same
+    # — a compiled program with NO collective, timed by the same
     # protocol. exposed_comm = total − compute is then the measured
     # exchange exposure each engine leaves on the critical path; the
     # pipelined engines' job is driving it toward zero at 4+ shards.

@@ -29,8 +29,8 @@ host-sync       from every jitted / shard_map'ped entry point (ops/,
 axis-name       ppermute/psum/pmax/axis_index/... must run under an
                 enclosing shard_map/pmap wrapper (reachability over the
                 call graph), and literal axis names must be bound
-                somewhere in the tree (the bug class
-                util/shard_map_compat papers over).
+                somewhere in the tree (an unbound name fails only when
+                the collective is traced).
 epoch-bump      any function mutating index storage (data / indices /
                 list_sizes / pq_codes / _db / the lifecycle tombstone
                 mask ``deleted``, incl. setattr) must bump an

@@ -2,10 +2,11 @@
 
 The reference's host-side runtime is C++ (raft_runtime, host refine,
 IO in benches); this package loads the TPU build's C++ analog. The library
-is compiled on demand with the in-repo Makefile (g++ is baked into the
-image; pybind11 is not, hence the C ABI + ctypes). Every entry point has a
-NumPy fallback in its caller, so a missing/broken toolchain degrades
-gracefully rather than failing imports.
+is built from the checkout's source by the in-repo Makefile on first use
+in each process (pybind11 is not available, hence the C ABI + ctypes);
+make rebuilds it whenever host_runtime.cpp is newer, so a stale binary is
+never loaded. Every entry point has a NumPy fallback in its caller, so a
+missing/broken toolchain degrades gracefully rather than failing imports.
 """
 
 from __future__ import annotations
@@ -61,20 +62,20 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None when unavailable."""
+    """Build (make skips an up-to-date library) and load the native
+    library; None when it cannot be built or loaded."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         path = os.path.join(_HERE, _LIB_NAME)
-        if not os.path.exists(path):
-            try:
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR],
-                    check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+        try:
+            subprocess.run(
+                ["make", "-C", _NATIVE_DIR],
+                check=True, capture_output=True, timeout=120)
+        except (OSError, subprocess.SubprocessError):
+            return None
         try:
             _lib = _configure(ctypes.CDLL(path))
         except OSError:

@@ -22,8 +22,8 @@ TPU-native re-design:
   mesocluster. Ownership masking decouples the EM into exactly the
   per-mesocluster sub-problems of the reference's ``build_hierarchical``
   host loop — but as ONE jitted program with O(1) host round-trips
-  instead of O(mesoclusters) device calls (the round-1 build spent
-  ~520 s in host-orchestrated sub-fits over a ~100 ms-RTT device link).
+  instead of O(mesoclusters) synchronized device calls, each of which
+  leaves the device idle while the host waits on it.
 
 Integer dtypes (SIFT-style uint8/int8) are accepted and mapped to float32
 on entry, the role of ``utils::mapping<T>`` in the reference.
@@ -176,8 +176,8 @@ def _hierarchical_fine_em(X, meso_labels, owner, seed_slots, key,
       while staying a single static-shape XLA program. The fine EM runs
       plain masked Lloyd iterations; under-population repair
       (adjust_centers) is deferred to the unmasked final polish —
-      measured recall/balance on 1M clustered rows matches the per-subfit
-      reseeding it replaces (BASELINE.md).
+      measured recall/balance on 1M clustered rows matched the per-subfit
+      reseeding it replaces.
 
     ``owner`` is (n_clusters,) int32: the owning mesocluster of each fine
     centroid. ``seed_slots`` is (max_quota, n_meso) int32: the fine-centroid

@@ -33,8 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from raft_tpu.util.shard_map_compat import axis_size
-
 
 class DatatypeT(enum.Enum):
     """Ref: comms_t::datatype_t (core/comms.hpp:33). JAX arrays carry their
@@ -89,7 +87,7 @@ class Comms:
             for a in axes:
                 n *= self.mesh.shape[a]
             return n
-        return axis_size(self.axis)
+        return lax.axis_size(self.axis)
 
     def get_rank(self):
         """Ref: comms_t::get_rank. Only meaningful inside shard_map."""
@@ -213,14 +211,14 @@ class Comms:
     def device_sendrecv(self, x, dest: int, source: int):
         """Paired send/recv (ref: comms_t::device_sendrecv,
         core/comms.hpp) — expressed as a ppermute over the send edges."""
-        size = self.get_size() if self.mesh is not None else axis_size(self.axis)
+        size = self.get_size() if self.mesh is not None else lax.axis_size(self.axis)
         perm = [(i, (i + dest - source) % size) for i in range(size)]
         return lax.ppermute(x, self.axis, perm)
 
     def shift(self, x, offset: int = 1):
         """Ring shift by ``offset`` (the ppermute idiom behind
         neighbor exchanges)."""
-        size = self.get_size() if self.mesh is not None else axis_size(self.axis)
+        size = self.get_size() if self.mesh is not None else lax.axis_size(self.axis)
         perm = [(i, (i + offset) % size) for i in range(size)]
         return lax.ppermute(x, self.axis, perm)
 
@@ -262,7 +260,6 @@ class Comms:
         """
         from raft_tpu.core.error import expects
         from raft_tpu.core.retry import with_retry
-        from raft_tpu.util.shard_map_compat import shard_map as _sm
 
         expects(self.mesh is not None,
                 "host_sendrecv needs a mesh-bound Comms (build_comms)")
@@ -278,11 +275,12 @@ class Comms:
             # addressable shards.
             xd = jax.make_array_from_callback(x.shape, sharding,
                                               lambda idx: x[idx])
-            fn = jax.jit(_sm(
+            fn = jax.jit(jax.shard_map(
                 lambda v: self.device_sendrecv(v, dest, source),
                 mesh=self.mesh,
                 in_specs=jax.sharding.PartitionSpec(self.axis),
-                out_specs=jax.sharding.PartitionSpec(self.axis)))
+                out_specs=jax.sharding.PartitionSpec(self.axis),
+                check_vma=False))
             out = fn(xd)
             # Rows addressable to THIS process (all rows on a single-
             # process mesh) — a process cannot read its peers' host

@@ -78,7 +78,6 @@ from raft_tpu.core.error import expects
 from raft_tpu.core.sentinels import worst_value
 from raft_tpu.util.pow2 import is_pow2
 from raft_tpu.util.telemetry import SuppressibleStats
-from raft_tpu.util.shard_map_compat import axis_size as _axis_size
 
 MERGE_ENGINES = ("auto", "allgather", "ring", "ring_bf16", "pipelined",
                  "pipelined_bf16")
@@ -411,7 +410,7 @@ def topk_merge(dist, idx, k: int, axis, select_min: bool = True,
     """
     expects(dist.ndim == 2 and dist.shape == idx.shape,
             "dist/idx must be (n_queries, kk) per-device candidates")
-    n_dev = _axis_size(axis)
+    n_dev = lax.axis_size(axis)
     q, kk = dist.shape
     k_out = min(k, n_dev * kk)
     engine = resolve_merge_engine(engine, q, k, n_dev)
@@ -488,7 +487,7 @@ def topk_merge_pipelined(scan_chunk, n_chunks: int, k: int, axis,
     ``min(k, Σ_c n_dev·kk_c)`` — the same width the unchunked merge of
     the concatenated candidates would return.
     """
-    n_dev = _axis_size(axis)
+    n_dev = lax.axis_size(axis)
     acc_d = acc_i = None
     for c in range(n_chunks):
         # named_scope per chunk: the obs layer's HLO tag splitting the

@@ -41,8 +41,8 @@ from jax.experimental.pallas import tpu as pltpu
 from raft_tpu.core.error import expects
 from raft_tpu.core.mdarray import as_array
 from raft_tpu.core.sentinels import PAD_ID, dummy_key_val, worst_value
+from raft_tpu.ops import pallas_interpret
 from raft_tpu.util.pow2 import ceildiv, round_up_safe
-from raft_tpu.util.pallas_compat import TPUCompilerParams
 from raft_tpu.core.nvtx import traced
 
 
@@ -230,7 +230,7 @@ def _stream_select_min(values, k: int, interpret: bool = False):
             jax.ShapeDtypeStruct((bp, nc * _M), jnp.float32),
             jax.ShapeDtypeStruct((bp, nc * _M), jnp.int32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(values)
@@ -286,8 +286,7 @@ def _stream_top_k(values, k, select_min):
     keys = values.astype(jnp.float32)
     if not select_min:
         keys = -keys
-    interpret = jax.default_backend() != "tpu"
-    _, idx = _stream_select_min(keys, k, interpret=interpret)
+    _, idx = _stream_select_min(keys, k, interpret=pallas_interpret())
     return jnp.take_along_axis(values, idx, axis=-1), idx
 
 

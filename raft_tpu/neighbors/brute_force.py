@@ -37,6 +37,7 @@ from raft_tpu.distance.distance_types import (
     DistanceType, resolve_metric, value_form_select_min)
 from raft_tpu.distance.pairwise import distance as pairwise_distance_fn
 from raft_tpu.matrix.select_k import select_k
+from raft_tpu.ops import pallas_interpret
 from raft_tpu.util.pow2 import ceildiv
 from raft_tpu.core.nvtx import traced
 
@@ -165,7 +166,7 @@ def tiled_brute_force_knn(
 
             return fused_knn(queries, db, k,
                              metric="l2" if is_l2 else "ip", sqrt=sqrt,
-                             interpret=jax.default_backend() != "tpu")
+                             interpret=pallas_interpret())
         return _tiled_knn_l2(queries, db, k, sqrt,
                              min(tile_db, max(db.shape[0], 1)), is_l2)
 

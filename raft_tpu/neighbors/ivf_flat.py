@@ -42,6 +42,7 @@ from raft_tpu.cluster.kmeans_types import KMeansBalancedParams
 from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.distance.distance_types import DistanceType, is_min_close, resolve_metric
 from raft_tpu.matrix.select_k import select_k
+from raft_tpu.ops import pallas_interpret
 from raft_tpu.random.rng_state import RngState
 from raft_tpu.util.pow2 import ceildiv, next_pow2, round_up_safe
 from raft_tpu.core.nvtx import traced
@@ -208,27 +209,35 @@ def _pack_lists(
     the interleaved-group layout: rows are sorted by list, positions within
     each list computed from offset prefix sums, then scattered.
     """
-    n, d = X.shape
     labels = labels.astype(jnp.int32)
     counts = jnp.bincount(labels, length=n_lists)
     cap = int(max(int(jnp.max(counts)), 1, min_cap))
-
-    order = jnp.argsort(labels, stable=True)
-    sorted_labels = labels[order]
-    offsets = jnp.concatenate([jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])[:-1]
-    pos = jnp.arange(n, dtype=jnp.int32) - offsets[sorted_labels].astype(jnp.int32)
-
     # Build-time one-shot: the bulk-fill caller passes a next_pow2
     # min_cap so steady-state capacity classes stay bucketed; only
     # conservative_memory_allocation opts into exact-fit shapes (and
     # pays a rebuild-grade compile when capacity moves, documented).
-    # analyze: recompile-risk-ok (build-time pack; bulk path is pow2-bucketed)
-    data = jnp.zeros((n_lists, cap, d), X.dtype)
-    idx = jnp.full((n_lists, cap), -1,  # analyze: recompile-risk-ok (see above)
-                   ids.dtype)
-    data = data.at[sorted_labels, pos].set(X[order])
-    idx = idx.at[sorted_labels, pos].set(ids[order])
+    data, idx = _fill_lists(X, labels, ids, counts, n_lists=n_lists,
+                            cap=cap)
     return data, idx, counts.astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("n_lists", "cap"))
+def _fill_lists(X, labels, ids, counts, *, n_lists: int, cap: int):
+    """The padded (n_lists, cap, dim) storage of :func:`_pack_lists` as
+    one program: the scatter fills the zero storage in place (eager ops
+    would hold the zeros and the filled copy at once) on the device that
+    holds ``X``."""
+    n, d = X.shape
+    order = jnp.argsort(labels, stable=True)
+    sorted_labels = labels[order]
+    offsets = jnp.concatenate([jnp.zeros((1,), counts.dtype),
+                               jnp.cumsum(counts)])[:-1]
+    pos = (jnp.arange(n, dtype=jnp.int32)
+           - offsets[sorted_labels].astype(jnp.int32))
+    data = jnp.zeros((n_lists, cap, d), X.dtype)
+    idx = jnp.full((n_lists, cap), -1, ids.dtype)
+    return (data.at[sorted_labels, pos].set(X[order]),
+            idx.at[sorted_labels, pos].set(ids[order]))
 
 
 def _train_centers(params, Xf: jax.Array) -> jax.Array:
@@ -572,8 +581,8 @@ def _chunked_over_queries(fn, Q, probe_ids, per_q_bytes: int,
     if nq <= chunk:
         return fn(Q, probe_ids)
     # Pad the ragged tail up to the shared chunk shape so every chunk hits
-    # one XLA compilation (a distinct tail shape would compile twice over
-    # the high-latency device link); padded rows are sliced off after.
+    # one XLA compilation (a distinct tail shape would compile a second
+    # program); padded rows are sliced off after.
     pad = (-nq) % chunk
     if pad:
         Q = jnp.concatenate([Q, jnp.broadcast_to(Q[:1], (pad, Q.shape[1]))])
@@ -636,7 +645,7 @@ def _pick_engine(engine: str, n_queries: int, n_probes: int, n_lists: int,
 
     ``cap_cache`` (a dict owned by the Index) memoizes the measured
     capacity per (n_queries, n_probes) so a steady-state query loop pays
-    the ~RTT-bound scalar readback once, not per call — the role of the
+    the synchronizing scalar readback once, not per call — the role of the
     reference's per-index ``get_max_batch_size`` heuristic
     (detail/ivf_pq_search.cuh:1517). The memo assumes batches drawn from
     a stationary query distribution: the capacity is measured on the
@@ -875,11 +884,12 @@ def _route_candidates(bd_, gi, route, q: int, p: int, bucket_cap: int,
 
 
 # Query-slot width of one packed cell (see _invert_probe_map_cells), the
-# VMEM budget for one list's data block in the cells kernel, and the
+# VMEM the cells kernel may ask for (one list's block double-buffered
+# plus its selection tiles, ops/fused_knn._cells_vmem_bytes), and the
 # widest top-k queue the cells kernels carry (two 128-lane groups — the
 # reference warpsort's kMaxCapacity=256, select_warpsort.cuh:100).
 _CELL_QROWS = 64
-_CELL_DB_BYTES = 6 * 1024 * 1024
+_CELL_VMEM_BYTES = 96 * 1024 * 1024      # of the v5e's 128 MiB of VMEM
 _CELLS_MAX_K = 256
 
 
@@ -890,14 +900,16 @@ def _cells_eligible(engine: str, k: int, bucket_cap: int, cap: int,
     by :func:`search` and the sharded search (parallel/ivf.py) so the
     two paths cannot drift: engine allows it, k within the cells queue,
     no explicit bucket_cap (which keeps the legacy bucket-table engine),
-    the per-list data block within the VMEM budget (f32 accounting — the
-    kernel's L2 epilogue upcasts bf16 storage), and for "auto" a TPU
+    the kernel's VMEM for one list within the budget (f32 accounting —
+    the kernel's L2 epilogue upcasts bf16 storage), and for "auto" a TPU
     backend with enough probe load to fill the tiles."""
     if not (engine in ("auto", "bucketed") and k <= _CELLS_MAX_K
             and bucket_cap == 0):
         return False
-    cap_bytes = round_up_safe(cap, 128) * round_up_safe(dim, 128) * 4
-    if cap_bytes > _CELL_DB_BYTES:
+    from raft_tpu.ops.fused_knn import _cells_vmem_bytes
+
+    if _cells_vmem_bytes(_CELL_QROWS, round_up_safe(cap, 128),
+                         round_up_safe(dim, 128)) > _CELL_VMEM_BYTES:
         return False
     if engine == "bucketed":
         return True
@@ -1008,7 +1020,7 @@ def search(
             Q, index.centers, dataf, index.indices, index.list_sizes,
             n_probes, k, inner_is_l2, sqrt,
             min(_CELL_QROWS, max(8, Q.shape[0])), qsplit,
-            jax.default_backend() != "tpu", deleted=index.deleted)
+            pallas_interpret(), deleted=index.deleted)
 
     # Coarse quantizer: distances to centers + top-n_probes
     # (ref: select_clusters-analog in ivf_flat_search; the cells path
@@ -1023,7 +1035,7 @@ def search(
         return _bucketed_probe_scan(
             Q, dataf, index.indices, index.list_sizes, probe_ids,
             k, inner_is_l2, sqrt, cap_q,
-            jax.default_backend() != "tpu", qsplit,
+            pallas_interpret(), qsplit,
             deleted=index.deleted)
 
     if inner_is_l2:

@@ -10,9 +10,20 @@ HBM. Everything here has an XLA fallback in its caller; kernels are used
 when the backend is TPU (or explicitly, in interpret mode, for tests).
 """
 
+import jax
+
 from raft_tpu.ops.fused_knn import fused_knn, fused_knn_supported
+
+
+def pallas_interpret() -> bool:
+    """Interpret mode for the kernels a caller dispatches: never on a
+    TPU, where they compile to Mosaic, and always elsewhere (the CPU
+    test mesh), where the interpreter is the only way they run."""
+    return jax.default_backend() != "tpu"
+
 
 __all__ = [
     "fused_knn",
     "fused_knn_supported",
+    "pallas_interpret",
 ]

@@ -37,7 +37,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from raft_tpu.util.pow2 import round_up_safe
-from raft_tpu.util.pallas_compat import TPUCompilerParams
 
 _LANES = 128
 _I32MAX = jnp.iinfo(jnp.int32).max
@@ -50,14 +49,20 @@ def _distance_tile(q, y, l2: bool, bf16: bool, qsplit: bool):
     expanded-L2 epilogue, or negated inner products (min-select order).
     Precision-sensitive — keep it single-sourced."""
     dims = (((1,), (1,)), ((), ()))
+    # bf16 operands take one MXU pass with f32 accumulation. The precision
+    # is pinned: a process-wide jax_default_matmul_precision of "highest"
+    # would otherwise ask Mosaic for a bf16 dot it refuses to lower.
+    one_pass = jax.lax.Precision.DEFAULT
     if bf16 and qsplit:
         yc = y.astype(jnp.bfloat16)
         qh = q.astype(jnp.bfloat16)
         ql = (q - qh.astype(jnp.float32)).astype(jnp.bfloat16)
         g = (jax.lax.dot_general(qh, yc, dimension_numbers=dims,
-                                 preferred_element_type=jnp.float32)
+                                 preferred_element_type=jnp.float32,
+                                 precision=one_pass)
              + jax.lax.dot_general(ql, yc, dimension_numbers=dims,
-                                   preferred_element_type=jnp.float32))
+                                   preferred_element_type=jnp.float32,
+                                   precision=one_pass))
     else:
         if bf16:
             qc, yc = q.astype(jnp.bfloat16), y.astype(jnp.bfloat16)
@@ -66,7 +71,7 @@ def _distance_tile(q, y, l2: bool, bf16: bool, qsplit: bool):
         g = jax.lax.dot_general(
             qc, yc, dimension_numbers=dims,
             preferred_element_type=jnp.float32,
-            precision=(None if bf16 else jax.lax.Precision.HIGHEST))
+            precision=one_pass if bf16 else jax.lax.Precision.HIGHEST)
     if not l2:
         return -g
     yf = y.astype(jnp.float32)  # norms in f32 even for bf16-stored db
@@ -195,7 +200,7 @@ def _fused_knn(queries, db, k: int, l2: bool, sqrt: bool,
             jax.ShapeDtypeStruct((mp, kp), jnp.float32),
             jax.ShapeDtypeStruct((mp, kp), jnp.int32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(queries, db)
@@ -295,7 +300,7 @@ def _fused_batch_knn(queries, db, bad, k: int, l2: bool, sqrt: bool,
             jax.ShapeDtypeStruct((B, mp, kp), jnp.float32),
             jax.ShapeDtypeStruct((B, mp, kp), jnp.int32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(queries, db, bad)
@@ -401,6 +406,7 @@ def fused_cells_knn(cell_list, queries, db, invalid, k: int, *,
 
     kernel = functools.partial(
         _cells_knn_kernel, k=k, kp=kp, l2=l2, bf16=bf16, qsplit=qsplit)
+    vmem = _cells_vmem_bytes(qr, capp, dp)
 
     def by_list(b, cl):
         return (jnp.maximum(cl[b], 0), 0, 0)
@@ -430,9 +436,25 @@ def fused_cells_knn(cell_list, queries, db, invalid, k: int, *,
             jax.ShapeDtypeStruct((max_cells, qr, kp), jnp.float32),
             jax.ShapeDtypeStruct((max_cells, qr, kp), jnp.int32),
         ],
+        compiler_params=(pltpu.CompilerParams(vmem_limit_bytes=vmem)
+                         if vmem > _SCOPED_VMEM_DEFAULT else None),
         interpret=interpret,
     )(cell_list, queries, db, invalid[:, None, :])
     return outd[:, :qrows, :k], outi[:, :qrows, :k]
+
+
+# Mosaic's default scoped-VMEM limit per kernel (v5e); past it the cells
+# kernel asks for what it needs (budgeted by ivf_flat._CELL_VMEM_BYTES).
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
+
+
+def _cells_vmem_bytes(qr: int, capp: int, dp: int) -> int:
+    """Scoped VMEM the cells kernel needs: one list's (cap, d) f32 block
+    and its mask row, each double-buffered, plus the (qrows, cap) score
+    tile and the ids and selection temporaries the k-pass keeps beside
+    it, and 4 MiB for the query and output blocks."""
+    return (2 * capp * (dp + 8) * 4 + 6 * qr * capp * 4
+            + 4 * 1024 * 1024)
 
 
 def fused_knn_supported(m: int, n: int, d: int, k: int) -> bool:

@@ -144,7 +144,7 @@ def book_tables(pq_centers: jax.Array, pq_bits: int, int8: bool = False):
     relative to the absolute embedding magnitude: an offset-dominated
     geometry (queries inside tight far-from-origin clusters) measured
     recall 0.115 vs the LUT scan's 0.908 because neighbor gaps sat
-    below bf16 resolution at the offset (BASELINE.md round 5). Sharing
+    below bf16 resolution at the offset (BENCH_r05-era 1M runs). Sharing
     one table also cuts the scan operands from n_lists·rot·128 f32
     (134 MB at the 1M default config) to rot·256 f32 (~130 KB)."""
     J, B, L = pq_centers.shape
@@ -252,10 +252,12 @@ def _pq_scan_cell_body(rotq_ref, codesT_ref, lo_ref, hi_ref, bad_ref,
             cwT = jnp.where(idx >= _LANES, ghi, glo)
         else:
             cwT = glo                               # (rot, 128) f32
+        # One bf16 MXU pass, pinned like ops/fused_knn._distance_tile.
         g = jax.lax.dot_general(                    # (bq, 128) f32
             rqb, cwT.astype(jnp.bfloat16),
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
         if is_ip:
             return -g
         cwn = jnp.sum(cwT * cwT, axis=0, keepdims=True)  # (1, 128)

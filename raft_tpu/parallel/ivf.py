@@ -52,6 +52,7 @@ from raft_tpu.core.sentinels import PAD_ID, worst_value
 from raft_tpu.distance.distance_types import DistanceType
 from raft_tpu.neighbors import ivf_flat as _flat
 from raft_tpu.neighbors import ivf_pq as _pq
+from raft_tpu.ops import pallas_interpret
 from raft_tpu.parallel.degraded import (
     check_live_mask,
     live_args,
@@ -73,7 +74,6 @@ from raft_tpu.parallel.routing import (
 )
 from raft_tpu.util.atomic_io import DEFAULT_IO, FileIO, atomic_savez
 from raft_tpu.util.pow2 import ceildiv, next_pow2
-from raft_tpu.util.shard_map_compat import shard_map
 
 
 @dataclass
@@ -202,18 +202,44 @@ def _shard_pack(mesh: Mesh, axis: str, rows, labels_h, ids, n_lists: int):
                                 minlength=n_lists)
     cap = next_pow2(int(counts.max()))
 
-    packed = [
-        _flat._pack_lists(rows[s * shard:(s + 1) * shard],
-                          jnp.asarray(labels_h[s * shard:(s + 1) * shard]),
-                          ids[s * shard:(s + 1) * shard], n_lists,
-                          min_cap=cap)
-        for s in range(n_dev)
-    ]
+    def inputs(s):
+        rs = slice(s * shard, (s + 1) * shard)
+        return rows[rs], labels_h[rs], ids[rs]
+
+    return _place_packs(
+        mesh, axis, inputs,
+        lambda x, lab, i: _flat._pack_lists(x, lab, i, n_lists,
+                                            min_cap=cap))
+
+
+def _place_packs(mesh: Mesh, axis: str, inputs, pack):
+    """Pack every shard on a device that holds it and assemble the
+    per-shard tuples into arrays sharded over ``mesh[axis]``.
+
+    ``inputs(s)`` selects shard ``s``'s rows; they move to the shard's
+    first device and ``pack(*inputs)`` runs there, and the other devices
+    holding shard ``s`` get copies. The source device thus holds only
+    its own shard's padded lists: a 4M-row corpus's packs, built or
+    stacked on one device, do not fit one v5e's HBM."""
+    n_dev = mesh.shape[axis]
     sharding = NamedSharding(mesh, P(axis))
-    data = jax.device_put(jnp.stack([p[0] for p in packed]), sharding)
-    idx = jax.device_put(jnp.stack([p[1] for p in packed]), sharding)
-    sizes = jax.device_put(jnp.stack([p[2] for p in packed]), sharding)
-    return data, idx, sizes
+    where = sharding.addressable_devices_indices_map((n_dev,))
+    parts = {}
+    for s in range(n_dev):
+        devs = [d for d, index in where.items()
+                if (index[0].start or 0) == s]
+        if not devs:
+            continue
+        packed = pack(*jax.device_put(inputs(s), devs[0]))
+        for dev in devs:
+            parts[dev] = [jax.device_put(a, dev)[None] for a in packed]
+        del packed
+    devs = list(where)
+    return tuple(
+        jax.make_array_from_single_device_arrays(
+            (n_dev,) + parts[devs[0]][i].shape[1:], sharding,
+            [parts[d][i] for d in devs])
+        for i in range(len(parts[devs[0]])))
 
 
 def _list_pack(mesh: Mesh, axis: str, rows, labels_h, ids, n_lists: int,
@@ -238,17 +264,15 @@ def _list_pack(mesh: Mesh, axis: str, rows, labels_h, ids, n_lists: int,
     # Remap global list labels to (owner, local slot); pack per shard.
     owner_r = pm.owner[labels_h]
     slot_r = pm.slot[labels_h]
-    packed = []
-    for s in range(n_dev):
+
+    def inputs(s):
         sel = np.flatnonzero(owner_r == s)
-        packed.append(_flat._pack_lists(
-            rows[sel], jnp.asarray(slot_r[sel]), ids[sel], pm.n_slots,
-            min_cap=cap))
-    sharding = NamedSharding(mesh, P(axis))
-    data = jax.device_put(jnp.stack([p[0] for p in packed]), sharding)
-    idx = jax.device_put(jnp.stack([p[1] for p in packed]), sharding)
-    sizes = jax.device_put(jnp.stack([p[2] for p in packed]), sharding)
-    return data, idx, sizes, pm
+        return rows[sel], slot_r[sel], ids[sel]
+
+    return _place_packs(
+        mesh, axis, inputs,
+        lambda x, slot, i: _flat._pack_lists(x, slot, i, pm.n_slots,
+                                             min_cap=cap)) + (pm,)
 
 
 def sharded_ivf_flat_build(
@@ -375,10 +399,10 @@ def _sharded_flat_search_jit(data, indices, sizes, centers, Q, live=None,
     extra_in, extra_out = live_specs(has_live)
     if has_tomb:
         extra_in = extra_in + (P(axis),)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(), P()) + extra_in,
-        out_specs=(P(), P()) + extra_out)
+        out_specs=(P(), P()) + extra_out, check_vma=False)
     args = live_args(live) + ((tomb,) if has_tomb else ())
     return fn(data, indices, sizes, centers, Q, *args)
 
@@ -480,7 +504,7 @@ def sharded_ivf_flat_search(
         live, index.deleted, mesh=mesh, axis=index.axis, k=k, n_probes=n_probes,
         inner_is_l2=inner_is_l2, sqrt=sqrt, use_cells=use_cells,
         qrows=min(_flat._CELL_QROWS, max(8, Q.shape[0])),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
         engine=engine, chunks=chunks)
 
 
@@ -717,11 +741,11 @@ def _routed_flat_search_jit(data, indices, sizes, Q, q_rows, probe_slots,
         return out_d, out_i
 
     extra = (P(axis),) if has_tomb else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(), P(axis), P(axis))
         + extra,
-        out_specs=(P(), P()))
+        out_specs=(P(), P()), check_vma=False)
     args = (tomb,) if has_tomb else ()
     return fn(data, indices, sizes, Q, q_rows, probe_slots, *args)
 
@@ -754,7 +778,7 @@ def _routed_flat_search(mesh, params, index, Q, k: int, merge_engine,
         probe_slots, index.deleted, mesh=mesh, axis=index.axis, k=k,
         inner_is_l2=inner_is_l2, sqrt=sqrt, use_cells=use_cells,
         qrows=min(_flat._CELL_QROWS, max(8, plan.qg)),
-        interpret=jax.default_backend() != "tpu", engine=engine,
+        interpret=pallas_interpret(), engine=engine,
         chunks=chunks)
     return _routed_result(out, plan, live_mask, Q.shape[0])
 
@@ -835,11 +859,11 @@ def _routed_pq_lut_jit(codes, indices, sizes, crot_slot, books, rot, Q,
 
     books_spec = P(axis) if per_cluster else P()
     extra = (P(axis),) if has_tomb else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), books_spec, P(),
                   P(), P(axis), P(axis)) + extra,
-        out_specs=(P(), P()))
+        out_specs=(P(), P()), check_vma=False)
     args = (tomb,) if has_tomb else ()
     return fn(codes, indices, sizes, crot_slot, books, rot, Q, q_rows,
               probe_slots, *args)
@@ -891,11 +915,11 @@ def _routed_pq_compressed_jit(codesT, invalid, indices, crot_p_slot,
             out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
         return out_d, out_i
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
                   P(), P(axis), P(axis)),
-        out_specs=(P(), P()))
+        out_specs=(P(), P()), check_vma=False)
     return fn(codesT, invalid, indices, crot_p_slot, abs_lo, abs_hi,
               rot, Q, q_rows, probe_slots)
 
@@ -932,7 +956,7 @@ def _routed_pq_search(mesh, params, index, Q, k: int, merge_engine,
             axis=index.axis, k=k, is_ip=is_ip, pq_dim=index.pq_dim,
             pq_bits=index.pq_bits, sqrt=sqrt,
             qrows=min(_pq._CELL_QROWS, max(8, plan.qg)),
-            interpret=jax.default_backend() != "tpu", engine=engine,
+            interpret=pallas_interpret(), engine=engine,
             chunks=chunks)
     else:
         per_cluster = index.codebook_kind == _pq.CodebookGen.PER_CLUSTER
@@ -1127,11 +1151,11 @@ def _sharded_pq_compressed_jit(codesT, invalid, indices, centers, rot,
         return out_d, out_i, cov
 
     extra_in, extra_out = live_specs(has_live)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(), P(), P(), P(), P(),
                   P()) + extra_in,
-        out_specs=(P(), P()) + extra_out)
+        out_specs=(P(), P()) + extra_out, check_vma=False)
     return fn(codesT, invalid, indices, centers, rot, abs_lo, abs_hi,
               crot_p, Q, *live_args(live))
 
@@ -1188,11 +1212,11 @@ def _sharded_pq_search_jit(codes, indices, sizes, centers, rot, books, Q,
     extra_in, extra_out = live_specs(has_live)
     if has_tomb:
         extra_in = extra_in + (P(axis),)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(), P(), P(), P())
         + extra_in,
-        out_specs=(P(), P()) + extra_out)
+        out_specs=(P(), P()) + extra_out, check_vma=False)
     args = live_args(live) + ((tomb,) if has_tomb else ())
     return fn(codes, indices, sizes, centers, rot, books, Q, *args)
 
@@ -1283,7 +1307,7 @@ def sharded_ivf_pq_search(
             is_ip=is_ip, pq_dim=index.pq_dim, pq_bits=index.pq_bits,
             sqrt=sqrt,
             qrows=min(_pq._CELL_QROWS, max(8, Q.shape[0])),
-            interpret=jax.default_backend() != "tpu", engine=engine,
+            interpret=pallas_interpret(), engine=engine,
             chunks=chunks)
     return _sharded_pq_search_jit(
         index.pq_codes, index.indices, index.list_sizes, index.centers,

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from raft_tpu.util.shard_map_compat import shard_map
 
 from raft_tpu.comms.topk_merge import resolve_merge_engine, topk_merge
 from raft_tpu.core.error import expects
@@ -51,11 +50,10 @@ def _em_body(axis: str, n_clusters: int):
 def _sharded_em_step_jit(X, centroids, *, mesh, axis, k):
     # jit around shard_map is load-bearing: un-jitted shard_map runs in the
     # eager SPMD interpreter (~10x slower, measured on the CPU mesh).
-    fn = shard_map(
+    fn = jax.shard_map(
         _em_body(axis, k), mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
-        out_specs=(P(None, None), P()),
-    )
+        out_specs=(P(None, None), P()), check_vma=False)
     return fn(X, centroids)
 
 
@@ -153,9 +151,9 @@ def _sharded_balanced_em_jit(X, centroids0, *, mesh, axis, n_iters,
 
         return lax.fori_loop(0, n_iters, em, c0)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis, None), P(None, None)),
-                   out_specs=P(None, None))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis, None), P(None, None)),
+                       out_specs=P(None, None), check_vma=False)
     return fn(X, centroids0)
 
 

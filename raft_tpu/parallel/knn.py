@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from raft_tpu.util.shard_map_compat import shard_map
 
 from raft_tpu.comms.topk_merge import (
     merge_dispatch_stats,
@@ -41,7 +40,7 @@ from raft_tpu.comms.topk_merge import (
     resolve_pipeline_chunks,
 )
 from raft_tpu.core.error import expects
-from raft_tpu.neighbors.brute_force import _tiled_knn_l2
+from raft_tpu.neighbors.brute_force import _TILE_DB, _tiled_knn_l2
 from raft_tpu.parallel.degraded import (
     check_live_mask,
     live_args,
@@ -51,6 +50,7 @@ from raft_tpu.parallel.degraded import (
     replicated,
     scan_merge_dispatch,
 )
+from raft_tpu.util.pow2 import ceildiv
 
 
 def shard_database(mesh: Mesh, db, axis: str = "data") -> jax.Array:
@@ -76,7 +76,7 @@ def sharded_knn(
     k: int,
     axis: str = "data",
     sqrt: bool = False,
-    tile_db: int = 8192,
+    tile_db: int = _TILE_DB,
     merge_engine: str = "auto",
     live_mask=None,
     pipeline_chunks: int = 0,
@@ -88,9 +88,10 @@ def sharded_knn(
     with global row ids. ``merge_engine`` picks the top-k merge collective
     (see comms/topk_merge.py): "allgather", "ring", "ring_bf16",
     "pipelined", "pipelined_bf16" or "auto". The pipelined engines chunk
-    each shard's row scan into ``pipeline_chunks`` tiles (0 = the
-    resolve_pipeline_chunks default) and overlap each finished tile's
-    ring exchange with the next tile's scan — bit-identical results
+    each shard's row scan into ``pipeline_chunks`` runs of whole
+    ``tile_db`` scan tiles (0 = the resolve_pipeline_chunks default; a
+    shard of one tile runs unchunked) and overlap each finished chunk's
+    ring exchange with the next chunk's scan — bit-identical results
     (docs/sharded_search.md §pipeline); "auto" here never picks them
     (the brute-force scan has no probe structure to key the heuristic
     on — opt in explicitly).
@@ -114,9 +115,16 @@ def sharded_knn(
     kk = min(k, shard)
     tile = min(tile_db, shard)
     engine = resolve_merge_engine(merge_engine, queries.shape[0], k, n_dev)
-    chunks = tuple(pipeline_chunk_bounds(
-        shard, resolve_pipeline_chunks(engine, shard, n_dev,
-                                       requested=pipeline_chunks)))
+    # Chunks are runs of whole scan tiles, so every chunk's distance
+    # matmuls have the unchunked scan's shapes: a matmul's summation order
+    # follows its shape, and a chunk of another width would differ from
+    # the allgather result in the last bit.
+    n_tiles = ceildiv(shard, tile)
+    chunks = tuple(
+        (lo * tile, min(hi * tile, shard))
+        for lo, hi in pipeline_chunk_bounds(
+            n_tiles, resolve_pipeline_chunks(engine, n_tiles, n_dev,
+                                             requested=pipeline_chunks)))
     # Host-side dispatch accounting for the metrics scrape (engine +
     # estimated exchange bytes; obs.registry.MergeDispatchCollector).
     # A chunked dispatch records ONE logical merge whose estimate sums
@@ -150,12 +158,12 @@ def _sharded_knn_jit(db, queries, live, *, mesh, axis, k, kk, sqrt, tile,
         alive = local_alive(rest[0], axis) if has_live else None
 
         def scan_range(lo, hi, kk_c):
-            # One row-tile scan; with the pipelined engines each tile's
-            # ring exchange overlaps the next tile's scan (chunks are
-            # disjoint row ranges, so results stay bit-identical to the
-            # eager chain — scan_merge_dispatch).
-            d_c, i_c = _tiled_knn_l2(q, db_local[lo:hi], kk_c, sqrt,
-                                     min(tile, hi - lo), True)
+            # One run of row tiles (a ragged last tile pads to the full
+            # tile, as in the unchunked scan); with the pipelined engines
+            # each chunk's ring exchange overlaps the next chunk's scan
+            # (scan_merge_dispatch).
+            d_c, i_c = _tiled_knn_l2(q, db_local[lo:hi], kk_c, sqrt, tile,
+                                     True)
             return d_c, i_c + (lax.axis_index(axis) * shard + lo)
 
         out_d, out_i = scan_merge_dispatch(
@@ -172,9 +180,9 @@ def _sharded_knn_jit(db, queries, live, *, mesh, axis, k, kk, sqrt, tile,
         return out_d, out_i, jnp.full((q.shape[0],), cov, jnp.float32)
 
     extra_in, extra_out = live_specs(has_live)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(axis, None), P(None, None)) + extra_in,
         out_specs=(P(None, None), P(None, None)) + extra_out,
-    )
+        check_vma=False)
     return fn(db, queries, *live_args(live))

@@ -12,8 +12,8 @@ The fix is the classic serving recipe (live in TF-Serving/JAX serving
 stacks as "shape bucketing"): quantize the query-count axis to a pow2
 ladder and k to a small fixed grid, pad every request up to its bucket,
 and pre-compile the full ``len(q_buckets) × len(k_grid)`` closed set at
-startup (:func:`warmup`, through the persistent compilation cache so
-even the first process boot on a machine pays it at most once).
+startup (:func:`warmup`; with the persistent compilation cache on —
+``core.compilation_cache`` — a later boot reads the programs back).
 Steady-state traffic inside the grid then NEVER compiles —
 ``tests/test_serve.py`` proves it with a compile-event hook.
 
@@ -130,20 +130,20 @@ def pad_queries(queries: np.ndarray, q_bucket: int) -> np.ndarray:
 
 
 def warmup(searcher, grid: BucketGrid, include_degraded: bool = False,
-           cache_dir: Optional[str] = None,
            degrade_ladder: Optional[Tuple[float, ...]] = None,
            min_probes: int = 1) -> dict:
-    """Pre-compile every bucket shape through the persistent compilation
-    cache, so steady-state in-grid traffic never compiles.
+    """Pre-compile every bucket shape, so steady-state in-grid traffic
+    never compiles.
 
     Runs one dummy search per ``grid.shapes()`` entry (zeros queries —
     the trace depends only on shapes/statics, never values).
     ``include_degraded=True`` additionally warms the liveness-operand
     trace (the program served while any shard is dead): the mask is a
     traced array operand, so warming with the all-live mask covers every
-    future mask value. Returns a report dict: shapes warmed, actual XLA
-    compile events observed (second boot on a machine reports ~0 — the
-    persistent cache served them), and the cache directory.
+    future mask value. Returns a report dict: shapes warmed and the XLA
+    compile events observed. The persistent compilation cache, where the
+    process turned it on (``core.compilation_cache``), serves these
+    compiles on a later boot; warmup itself leaves its placement alone.
 
     ``placement="list"`` (routed) searchers warm MORE than the grid
     shapes: a routed dispatch's program is keyed by the plan's pow2
@@ -163,7 +163,6 @@ def warmup(searcher, grid: BucketGrid, include_degraded: bool = False,
     value would compile in the hot path — exactly when latency is
     already collapsing.  Ignored for searchers without an ``n_probes``
     parameter (brute force)."""
-    from raft_tpu.core.compilation_cache import enable_compilation_cache
     from raft_tpu.core.logger import logger
     from raft_tpu.serve.stats import CompileCounter
 
@@ -173,7 +172,6 @@ def warmup(searcher, grid: BucketGrid, include_degraded: bool = False,
     expects(not include_degraded or getattr(searcher, "health", None)
             is not None,
             "include_degraded=True needs a searcher with ShardHealth")
-    effective_dir = enable_compilation_cache(cache_dir)
     dim = searcher.dim
     shapes = grid.shapes()
     # The ladder's closed n_probes set (deduped: min_probes and int
@@ -233,9 +231,9 @@ def warmup(searcher, grid: BucketGrid, include_degraded: bool = False,
                         searcher._index, qb, kb,
                         merge_engine=searcher.merge_engine)
     logger.debug("serve warmup: %s bucket shapes (+%s routed plan "
-                 "shapes), %s XLA compiles, cache at %s", len(shapes),
-                 routed_shapes, counter.count, effective_dir)
+                 "shapes), %s XLA compiles", len(shapes), routed_shapes,
+                 counter.count)
     return {"shapes": len(shapes), "degraded": bool(include_degraded),
             "routed_shapes": routed_shapes,
             "degrade_rungs": len(rung_probes),
-            "compile_events": counter.count, "cache_dir": effective_dir}
+            "compile_events": counter.count}

@@ -302,8 +302,13 @@ class Searcher:
         axis = getattr(self._index, "axis", "data")
         n_dev = self.mesh.shape[axis]
         if self.kind == "brute_force":
+            from raft_tpu.neighbors.brute_force import _TILE_DB
+            from raft_tpu.util.pow2 import ceildiv
+
+            # sharded_knn pipelines over whole row tiles of its shard.
             n_probes = None
-            n_items = int(self._db.shape[0]) // n_dev
+            shard = int(self._db.shape[0]) // n_dev
+            n_items = ceildiv(shard, min(_TILE_DB, shard))
         else:
             n_probes = min(self._params.n_probes,
                            int(self._index.centers.shape[0]))

@@ -508,7 +508,7 @@ def _block_dist(metric: DistanceType, p: float, d: int, dc: int,
 # Jitted whole-problem drivers: ONE dispatch covers every (x block, y
 # block) pair of a group pair — an outer lax.scan over x blocks wrapping
 # the inner y-block scan (VERDICT r2 weak #7: the previous host loop paid
-# one dispatch × link RTT per x block, ~500 sequential dispatches at 1M
+# one synchronized dispatch per x block, ~500 sequential dispatches at 1M
 # rows).
 
 

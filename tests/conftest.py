@@ -26,11 +26,6 @@ jax.config.update("jax_enable_x64", False)
 # (the production default keeps the TPU-fast bf16 MXU path).
 jax.config.update("jax_default_matmul_precision", "highest")
 
-# Persistent XLA compilation cache: repeat suite runs skip recompilation.
-from raft_tpu.core.compilation_cache import enable_compilation_cache  # noqa: E402
-
-enable_compilation_cache()
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

@@ -223,7 +223,7 @@ def test_search_traceable_under_jit(rng, monkeypatch):
         idx, q, 5))(Q)
     np.testing.assert_array_equal(np.asarray(i_b), np.asarray(i_e))
     # legacy bucket-table engine (cells gated off): traced cap=0 raises
-    monkeypatch.setattr(impl, "_CELL_DB_BYTES", 0)
+    monkeypatch.setattr(impl, "_CELL_VMEM_BYTES", 0)
     with pytest.raises(RaftError, match="bucket_cap"):
         jax.jit(lambda q: ivf_flat.search(
             ivf_flat.SearchParams(n_probes=8, engine="bucketed"),
@@ -261,7 +261,7 @@ def test_measured_cap_cached_per_index(rng, monkeypatch):
 
     # The measured-capacity machinery belongs to the legacy bucket-table
     # engine; gate the round-4 cells tier off to exercise it.
-    monkeypatch.setattr(impl, "_CELL_DB_BYTES", 0)
+    monkeypatch.setattr(impl, "_CELL_VMEM_BYTES", 0)
 
     calls = []
     real = impl._front_rank_contention
@@ -297,7 +297,7 @@ def test_skew_bound_never_drops_best_probe(rng, monkeypatch):
     construction; covered by the parity tests above)."""
     from raft_tpu.neighbors import ivf_flat as impl
 
-    monkeypatch.setattr(impl, "_CELL_DB_BYTES", 0)
+    monkeypatch.setattr(impl, "_CELL_VMEM_BYTES", 0)
 
     # One tight hot cluster + scattered others across 64 lists.
     hot = rng.normal(size=(400, 8)).astype(np.float32) * 0.05

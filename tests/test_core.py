@@ -237,50 +237,80 @@ class TestLoggerTrace:
 
 
 class TestCompilationCacheDir:
-    """enable_compilation_cache must respect an application-configured
-    jax_compilation_cache_dir unless a path is passed explicitly, and
-    return the effective directory (ISSUE 5 satellite)."""
+    """enable_compilation_cache places the persistent cache where the
+    deployment says (JAX_COMPILATION_CACHE_DIR, read by JAX into
+    jax_compilation_cache_dir) and otherwise at one fixed path inside
+    the checkout; it never picks a directory of its own beyond that."""
 
-    def test_respects_preconfigured_dir(self, tmp_path):
-        from raft_tpu.core.compilation_cache import enable_compilation_cache
+    @pytest.fixture
+    def clean_cache_config(self):
+        from jax.experimental.compilation_cache import compilation_cache
 
-        old = jax.config.jax_compilation_cache_dir
-        app_dir = str(tmp_path / "app_cache")
+        old_dir = jax.config.jax_compilation_cache_dir
+        old_min = jax.config.jax_persistent_cache_min_compile_time_secs
         try:
-            jax.config.update("jax_compilation_cache_dir", app_dir)
-            effective = enable_compilation_cache()
-            assert effective == app_dir
-            assert jax.config.jax_compilation_cache_dir == app_dir
+            yield
         finally:
-            jax.config.update("jax_compilation_cache_dir", old)
+            jax.config.update("jax_compilation_cache_dir", old_dir)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              old_min)
+            compilation_cache.reset_cache()
 
-    def test_explicit_path_still_wins(self, tmp_path):
+    def test_respects_preconfigured_dir(self, tmp_path, clean_cache_config):
         from raft_tpu.core.compilation_cache import enable_compilation_cache
 
-        old = jax.config.jax_compilation_cache_dir
         app_dir = str(tmp_path / "app_cache")
-        mine = str(tmp_path / "explicit")
-        try:
-            jax.config.update("jax_compilation_cache_dir", app_dir)
-            effective = enable_compilation_cache(mine)
-            assert effective == mine
-            assert jax.config.jax_compilation_cache_dir == mine
-            import os
+        jax.config.update("jax_compilation_cache_dir", app_dir)
+        effective = enable_compilation_cache()
+        assert effective == app_dir
+        assert jax.config.jax_compilation_cache_dir == app_dir
 
-            assert os.path.isdir(mine)
-        finally:
-            jax.config.update("jax_compilation_cache_dir", old)
+    def test_env_var_dir_is_left_alone(self, tmp_path):
+        """JAX reads JAX_COMPILATION_CACHE_DIR at import, so the rule is
+        checked in a fresh CPU-only interpreter."""
+        import os
+        import subprocess
+        import sys
 
-    def test_env_fallback_when_unconfigured(self, tmp_path, monkeypatch):
-        from raft_tpu.core.compilation_cache import enable_compilation_cache
-
-        old = jax.config.jax_compilation_cache_dir
         env_dir = str(tmp_path / "env_cache")
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-            monkeypatch.setenv("RAFT_TPU_XLA_CACHE", env_dir)
-            effective = enable_compilation_cache()
-            assert effective == env_dir
-            assert jax.config.jax_compilation_cache_dir == env_dir
-        finally:
-            jax.config.update("jax_compilation_cache_dir", old)
+        code = ("import jax\n"
+                "from raft_tpu.core.compilation_cache import "
+                "enable_compilation_cache\n"
+                "print(enable_compilation_cache())\n"
+                "print(jax.config.jax_compilation_cache_dir)\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=env_dir,
+                   PYTHONPATH=root + os.pathsep
+                   + os.environ.get("PYTHONPATH", ""))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120,
+                             check=True)
+        assert out.stdout.split() == [env_dir, env_dir]
+
+    def test_unconfigured_uses_checkout_dir(self, clean_cache_config):
+        import os
+
+        from raft_tpu.core.compilation_cache import (CHECKOUT_CACHE_DIR,
+                                                     enable_compilation_cache)
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compilation_cache() == CHECKOUT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == CHECKOUT_CACHE_DIR
+        assert os.path.isdir(CHECKOUT_CACHE_DIR)
+        # Repeat calls keep the same directory (a moved cache never hits).
+        assert enable_compilation_cache() == CHECKOUT_CACHE_DIR
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.2
+
+    def test_serve_warmup_leaves_cache_config_alone(self, clean_cache_config):
+        """Serving warmup compiles through whatever cache the process
+        has; placing the cache is the entry point's call."""
+        from raft_tpu.serve import BucketGrid, Searcher, warmup
+
+        jax.config.update("jax_compilation_cache_dir", None)
+        db = np.random.default_rng(0).normal(size=(64, 8)).astype(np.float32)
+        report = warmup(Searcher.brute_force(db), BucketGrid((1, 2), (1,)))
+        assert report["shapes"] == 2
+        assert jax.config.jax_compilation_cache_dir is None

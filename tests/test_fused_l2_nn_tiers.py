@@ -5,7 +5,7 @@ and the XLA fallback must keep the same numerics as the kernel path
 
 Ref bound culture: the reference keeps fusedL2NN f32
 (detail/fused_l2_nn.cuh:129); the split tier is the TPU extension the
-k-means inner loop now defaults to (BASELINE.md round 5), so its
+k-means inner loop now defaults to, so its
 agreement contract needs pinning.
 """
 

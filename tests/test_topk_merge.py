@@ -13,7 +13,6 @@ from raft_tpu.comms.topk_merge import (
     MERGE_ENGINES, merge_comm_bytes, merge_dispatch_stats, merge_parts,
     pipeline_chunk_bounds, resolve_merge_engine, resolve_pipeline_chunks,
     topk_merge, topk_merge_pipelined)
-from raft_tpu.util.shard_map_compat import shard_map
 
 
 def _mesh(n_dev):
@@ -25,11 +24,11 @@ def _mesh(n_dev):
 def _merge_on_mesh(mesh, dist, idx, k, select_min, engine):
     """dist/idx: (n_dev, q, kk) host arrays — row d is device d's local
     candidates; returns the replicated merged (distances, ids)."""
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda dd, ii: topk_merge(dd[0], ii[0], k, "data",
                                   select_min=select_min, engine=engine),
         mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=(P(None, None), P(None, None)))
+        out_specs=(P(None, None), P(None, None)), check_vma=False)
     d, i = jax.jit(fn)(jnp.asarray(dist), jnp.asarray(idx))
     return np.asarray(d), np.asarray(i)
 
@@ -143,8 +142,9 @@ def _pipelined_on_mesh(mesh, dist, idx, k, select_min, n_chunks,
                                     select_min=select_min,
                                     quantized=quantized)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=(P(None, None), P(None, None)))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                       out_specs=(P(None, None), P(None, None)),
+                       check_vma=False)
     d, i = jax.jit(fn)(jnp.asarray(dist), jnp.asarray(idx))
     return np.asarray(d), np.asarray(i)
 
@@ -236,11 +236,26 @@ class TestShardedPipelinedConsumers:
         mesh = _mesh(8)
         db = rng.normal(size=(1024, 16)).astype(np.float32)
         q = rng.normal(size=(32, 16)).astype(np.float32)
-        bd, bi = sharded_knn(mesh, db, q, k=10, merge_engine="allgather")
+        # 128 rows per shard in 16-row scan tiles: 3 chunks of whole tiles.
+        bd, bi = sharded_knn(mesh, db, q, k=10, merge_engine="allgather",
+                             tile_db=16)
         d, i = sharded_knn(mesh, db, q, k=10, merge_engine=engine,
-                           pipeline_chunks=3)
+                           pipeline_chunks=3, tile_db=16)
         np.testing.assert_array_equal(np.asarray(bd), np.asarray(d))
         np.testing.assert_array_equal(np.asarray(bi), np.asarray(i))
+
+    def test_searcher_pipeline_plan_counts_row_tiles(self, rng):
+        """The Searcher's chunk annotation follows sharded_knn's split
+        over whole scan tiles: a shard of one tile runs unchunked."""
+        from raft_tpu.serve import Searcher
+
+        mesh = _mesh(8)
+        small = rng.normal(size=(1024, 16)).astype(np.float32)
+        s = Searcher.brute_force(small, mesh=mesh, merge_engine="pipelined")
+        assert s._pipeline_plan(32, 10) is None
+        big = np.zeros((8 * 16 * 8192, 16), np.float32)  # 16 tiles a shard
+        s = Searcher.brute_force(big, mesh=mesh, merge_engine="pipelined")
+        assert s._pipeline_plan(32, 10) == ("pipelined", 4)
 
     @pytest.mark.parametrize("tier", ["scan", "bucketed"])
     @pytest.mark.parametrize("n_probes,chunks", [(7, 3), (8, 0), (5, 2)])
@@ -562,14 +577,15 @@ def test_merge_parts_unsigned_keys_select_max():
 
 
 def test_comms_axis_size_inside_shard_map():
-    """Comms.get_size() without a bound mesh resolves the axis size via
-    the util shim on every jax version (lax.axis_size is new in 0.5)."""
+    """Comms.get_size() without a bound mesh resolves the axis size as
+    ``lax.axis_size`` of the bound axis."""
     from raft_tpu.comms import Comms
 
     mesh = _mesh(4)
     comms = Comms(axis="data")
-    fn = shard_map(lambda x: x[0] * comms.get_size(), mesh=mesh,
-                   in_specs=(P("data"),), out_specs=P(None))
+    fn = jax.shard_map(lambda x: x[0] * comms.get_size(), mesh=mesh,
+                       in_specs=(P("data"),), out_specs=P(None),
+                       check_vma=False)
     out = jax.jit(fn)(jnp.ones((4, 2), jnp.int32))
     np.testing.assert_array_equal(np.asarray(out), np.full((2,), 4))
 
