@@ -1,0 +1,6 @@
+"""The device's ``peak_bytes_in_use`` after the window (build included),
+in GB (1e9 bytes)."""
+
+
+def read(run):
+    return run.peak_bytes / 1e9 if run.peak_bytes else None
