@@ -931,26 +931,32 @@ def _cells_scan_probes(Q, probe_ids, data, indices, list_sizes, k: int,
 
     q = Q.shape[0]
     n_lists, cap, _ = data.shape
-    cell_list, bucket, route = _invert_probe_map_cells(
-        probe_ids, n_lists, qrows)
-    Qc = Q[jnp.maximum(bucket, 0)]                 # (max_cells, qrows, d)
-    invalid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
-               >= list_sizes[:, None])
-    if deleted is not None:
-        invalid |= deleted           # tombstones mask exactly like padding
-    bd_, bi_ = fused_cells_knn(cell_list, Qc, data, invalid, k,
-                               l2=inner_is_l2,
-                               bf16=data.dtype == jnp.bfloat16,
-                               qsplit=qsplit, interpret=interpret)
-    gi = indices[jnp.maximum(cell_list, 0)[:, None, None],
-                 jnp.maximum(bi_, 0)]
-    gi = jnp.where(bi_ < 0, -1, gi)
-    # The kernel reports min-selection order (ip scores negated).
-    cd, ci = _route_candidates_cells(bd_, gi, route, q,
-                                     probe_ids.shape[1])
-    best_d, best_i = select_k(cd, k, select_min=True, indices=ci)
-    if not inner_is_l2:
-        best_d = -best_d
+    # Each stage is a named scope, so a profiler trace names the device
+    # operations it ran (metadata only: the program is the same).
+    with jax.named_scope("ivf_flat.cells_invert"):
+        cell_list, bucket, route = _invert_probe_map_cells(
+            probe_ids, n_lists, qrows)
+        Qc = Q[jnp.maximum(bucket, 0)]             # (max_cells, qrows, d)
+        invalid = (jnp.arange(cap, dtype=jnp.int32)[None, :]
+                   >= list_sizes[:, None])
+        if deleted is not None:
+            invalid |= deleted       # tombstones mask exactly like padding
+    with jax.named_scope("ivf_flat.cells_scan"):
+        bd_, bi_ = fused_cells_knn(cell_list, Qc, data, invalid, k,
+                                   l2=inner_is_l2,
+                                   bf16=data.dtype == jnp.bfloat16,
+                                   qsplit=qsplit, interpret=interpret)
+    with jax.named_scope("ivf_flat.id_gather"):
+        gi = indices[jnp.maximum(cell_list, 0)[:, None, None],
+                     jnp.maximum(bi_, 0)]
+        gi = jnp.where(bi_ < 0, -1, gi)
+    with jax.named_scope("ivf_flat.route_select"):
+        # The kernel reports min-selection order (ip scores negated).
+        cd, ci = _route_candidates_cells(bd_, gi, route, q,
+                                         probe_ids.shape[1])
+        best_d, best_i = select_k(cd, k, select_min=True, indices=ci)
+        if not inner_is_l2:
+            best_d = -best_d
     return best_d, best_i
 
 
@@ -962,7 +968,8 @@ def _cells_search(Q, centers, data, indices, list_sizes, n_probes: int,
     coarse probe, cells inversion, fused Pallas scan, routing and the
     final merge (the round-4 engine treatment applied to IVF-Flat: no
     bucket-capacity measurement, no probe drops, no eager glue)."""
-    probe_ids = _coarse_probe(Q, centers, n_probes, inner_is_l2)
+    with jax.named_scope("ivf_flat.coarse_probe"):
+        probe_ids = _coarse_probe(Q, centers, n_probes, inner_is_l2)
     best_d, best_i = _cells_scan_probes(Q, probe_ids, data, indices,
                                         list_sizes, k, inner_is_l2, qrows,
                                         qsplit, interpret, deleted)

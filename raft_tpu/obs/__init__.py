@@ -4,8 +4,10 @@ Three pillars over the serving stack (docs/observability.md):
 
 * ``obs.trace`` — deterministic request-span tracer on the injectable
   monotonic clock (queue-wait / batch-assembly / cache-lookup /
-  device-dispatch / result-merge / device_get spans per request),
-  exportable as JSON and Chrome trace-event format;
+  device-dispatch with enqueue and device-wait / result-merge /
+  device_get / collector-pause spans per batch and request, also
+  marked as ``raft_tpu::serve.*`` profiler ranges), exportable as JSON
+  and Chrome trace-event format;
 * ``obs.registry`` — ``MetricsRegistry`` (counters / gauges /
   histograms with labels, Prometheus text exposition + JSON snapshot)
   and the ``*Collector`` adapters unifying ``ServeStats``,

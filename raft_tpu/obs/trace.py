@@ -20,23 +20,50 @@ Disciplines (shared with serve/ and core/retry.py):
   and the device fence (``jax.block_until_ready`` in
   ``Searcher.search``) only runs when a recording span asks for it.
 * **Bounded retention** — finished request traces land in a ring buffer
-  (``max_traces``); a serving process must not grow without bound.
+  (``max_traces``) and collector pauses in a bounded deque; a serving
+  process must not grow without bound.
+* **Collector pauses are spans** — an enabled tracer records every
+  garbage-collector pause (start, stop, ``generation``, ``collected``)
+  from a ``gc.callbacks`` hook that holds the tracer only weakly; the
+  scheduler attaches them to the next batch it finishes.  A disabled
+  tracer registers no hook.
+* **The profiler's clock** — the batch root (:meth:`Tracer.scoped`),
+  every live child of it and every collector pause also hold a host
+  range ``raft_tpu::serve.<name>`` (``core/nvtx.push_range``) for
+  their lifetime, so under ``jax.profiler`` the program's own spans
+  land in the trace beside the device's operations.  The ranges are
+  host-only ``TraceAnnotation``s, never a ``named_scope``: they change
+  no compiled program.
 
 The device-side counterpart is ``jax.named_scope`` annotations on the
-sharded scan/merge stages (parallel/knn.py, parallel/ivf.py) — those
-tag HLO metadata for ``jax.profiler`` traces and cost nothing at
-runtime; this module owns the host-side request timeline.
+searched programs' stages (neighbors/ivf_flat.py, parallel/knn.py,
+parallel/ivf.py) — those tag HLO metadata for ``jax.profiler`` traces
+and cost nothing at runtime; this module owns the host-side request
+timeline.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from raft_tpu.core.nvtx import pop_range, push_range
+
 __all__ = ["Span", "Tracer", "NULL_SPAN", "NULL_TRACER"]
+
+#: Collector pauses an enabled tracer keeps until a batch takes them.
+MAX_PAUSES = 4096
+
+
+def _range_name(name: str) -> str:
+    """``serve.<name>``: the profiler range of a span (``push_range``
+    prefixes the ``raft_tpu::`` domain)."""
+    return name if name.startswith("serve.") else "serve." + name
 
 
 class Span:
@@ -45,10 +72,15 @@ class Span:
     :meth:`child` (started now, finish later / use as a context
     manager) or :meth:`child_at` (pre-measured interval — the scheduler
     measures one batch once and attaches the interval to every member
-    request's tree)."""
+    request's tree) or :meth:`copy_child` (a finished span and its
+    subtree, copied).
+
+    A ``ranged`` span holds a profiler range from its start until
+    :meth:`finish`, and so does every :meth:`child` of it: such spans
+    must open and finish on one thread, innermost first."""
 
     __slots__ = ("name", "start", "end", "attrs", "children", "tid",
-                 "_clock", "_sink")
+                 "_clock", "_sink", "_ranged")
 
     #: Real spans record; the :data:`NULL_SPAN` singleton reports False —
     #: the one flag instrumentation sites branch on (e.g. whether to pay
@@ -56,10 +88,14 @@ class Span:
     recording = True
 
     def __init__(self, name: str, clock: Callable[[], float], tid: int = 0,
-                 attrs: Optional[dict] = None, sink=None):
+                 attrs: Optional[dict] = None, sink=None,
+                 ranged: bool = False):
         self.name = name
         self._clock = clock
         self.tid = tid
+        self._ranged = ranged
+        if ranged:
+            push_range(_range_name(name))
         self.start = clock()
         self.end: Optional[float] = None
         self.attrs: Dict[str, object] = dict(attrs) if attrs else {}
@@ -70,7 +106,7 @@ class Span:
     def child(self, name: str, **attrs) -> "Span":
         """Start a child span now (finish it explicitly or via ``with``)."""
         sp = Span(name, self._clock, tid=self.tid,
-                  attrs=attrs if attrs else None)
+                  attrs=attrs if attrs else None, ranged=self._ranged)
         self.children.append(sp)
         return sp
 
@@ -85,16 +121,17 @@ class Span:
         self.children.append(sp)
         return sp
 
+    def copy_child(self, span: "Span") -> "Span":
+        """Attach a copy of the finished ``span`` and of its subtree
+        (:meth:`child_at` all the way down: same names, intervals and
+        attributes)."""
+        sp = self.child_at(span.name, span.start, span.end, **span.attrs)
+        for c in span.children:
+            sp.copy_child(c)
+        return sp
+
     def annotate(self, **attrs) -> None:
         self.attrs.update(attrs)
-
-    def now(self) -> float:
-        """The span's clock (the tracer's injected monotonic) — the
-        boundary instrumentation sites must read THIS clock when they
-        attach pre-measured ``child_at`` intervals, or exports stop
-        being deterministic under injection (Searcher.search's
-        pipeline-chunk waves use it)."""
-        return self._clock()
 
     def finish(self, **attrs) -> None:
         """Stamp the end time (idempotent — the first finish wins) and,
@@ -103,6 +140,8 @@ class Span:
             self.attrs.update(attrs)
         if self.end is None:
             self.end = self._clock()
+            if self._ranged:
+                pop_range()
             if self._sink is not None:
                 self._sink(self)
 
@@ -152,11 +191,11 @@ class _NullSpan:
     def child_at(self, name, start, end, **attrs):
         return self
 
+    def copy_child(self, span):
+        return self
+
     def annotate(self, **attrs):
         pass
-
-    def now(self) -> float:
-        return 0.0
 
     def finish(self, **attrs):
         pass
@@ -182,6 +221,8 @@ class Tracer:
     :meth:`request` into the shared :data:`NULL_SPAN` — the zero-cost
     contract instrumented code relies on.  Thread-safe: request threads
     open roots while a driver thread finishes them and a scraper drains.
+    An enabled tracer also records collector pauses
+    (:meth:`take_pauses`) until it is closed or collected.
     """
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
@@ -192,22 +233,44 @@ class Tracer:
         self._finished: deque = deque(maxlen=max_traces)
         self._dropped = 0
         self._tid = 0
-
-    def now(self) -> float:
-        """The tracer's clock (span boundary measurements must read THIS
-        clock so exports are deterministic under injection)."""
-        return self._clock()
+        self._gc_hook = _GcHook(self) if enabled else None
 
     def request(self, name: str, **attrs):
         """Open one request root span (finished roots land in the ring
         buffer for :meth:`take`); :data:`NULL_SPAN` when disabled."""
+        return self._root(name, attrs, ranged=False)
+
+    def scoped(self, name: str, **attrs):
+        """Open a root span that lives on the calling thread — the
+        scheduler's ``serve.batch``: it and every :meth:`Span.child` of
+        it also hold the profiler range ``raft_tpu::serve.<name>`` until
+        they finish, innermost first.  Finished like a request root;
+        :data:`NULL_SPAN` when disabled."""
+        return self._root(name, attrs, ranged=True)
+
+    def _root(self, name: str, attrs: dict, ranged: bool):
         if not self.enabled:
             return NULL_SPAN
         with self._lock:
             self._tid += 1
             tid = self._tid
         return Span(name, self._clock, tid=tid,
-                    attrs=attrs if attrs else None, sink=self._publish)
+                    attrs=attrs if attrs else None, sink=self._publish,
+                    ranged=ranged)
+
+    def take_pauses(self) -> List[tuple]:
+        """Drain the collector pauses recorded since the last call:
+        ``(start, end, generation, collected)`` on the tracer's clock,
+        oldest first (at most :data:`MAX_PAUSES`)."""
+        hook = self._gc_hook
+        return hook.take() if hook is not None else []
+
+    def close(self) -> None:
+        """Stop recording collector pauses (unregisters the ``gc``
+        hook; also done when the tracer is collected).  Idempotent."""
+        hook, self._gc_hook = self._gc_hook, None
+        if hook is not None:
+            hook.remove()
 
     def _publish(self, span: Span) -> None:
         with self._lock:
@@ -287,6 +350,58 @@ class Tracer:
     def __repr__(self) -> str:
         return ("Tracer(enabled=%s, pending=%d)"
                 % (self.enabled, self.pending))
+
+
+class _GcHook:
+    """The ``gc.callbacks`` entry of one enabled :class:`Tracer`: times
+    each collection on the tracer's clock, holds the profiler range
+    ``raft_tpu::serve.gc`` across it, and keeps the pauses in a bounded
+    deque.  It takes no lock — a collection can start while any lock of
+    the process is held, so the deque's atomic append and popleft are
+    all it relies on — and holds the tracer only weakly, removing itself
+    when the tracer is collected."""
+
+    def __init__(self, tracer: Tracer):
+        self._tracer = weakref.ref(tracer, self._dead)
+        self._start: Optional[float] = None
+        self._pauses: deque = deque(maxlen=MAX_PAUSES)
+        gc.callbacks.append(self)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        tracer = self._tracer()
+        if tracer is None:
+            return
+        if phase == "start":
+            push_range("serve.gc")
+            self._start = tracer._clock()
+        elif self._start is not None:
+            end = tracer._clock()
+            pop_range()
+            self._pauses.append((self._start, end, info["generation"],
+                                 info["collected"]))
+            self._start = None
+
+    def take(self) -> List[tuple]:
+        out = []
+        while True:
+            try:
+                out.append(self._pauses.popleft())
+            except IndexError:
+                return out
+
+    def _dead(self, _ref) -> None:
+        # A tracer in a reference cycle dies inside a collection, which
+        # then ends without calling this hook: close its range here.
+        if self._start is not None:
+            pop_range()
+            self._start = None
+        self.remove()
+
+    def remove(self) -> None:
+        try:
+            gc.callbacks.remove(self)
+        except ValueError:
+            pass
 
 
 #: Shared disabled tracer: the default wired into the scheduler so

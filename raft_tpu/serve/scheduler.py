@@ -235,6 +235,7 @@ class BatchScheduler:
         self._queue: List[_Pending] = []
         self._lock = threading.Lock()
         self._seq = itertools.count()
+        self._batch_seq = itertools.count()
         self._unhook = (searcher.add_invalidation_hook(cache.invalidate)
                         if cache is not None else None)
 
@@ -494,22 +495,22 @@ class BatchScheduler:
         bucket = (qb, kb)
         rung, reason, n_probes = self._pick_rung(batch, bucket)
         self.brownout_level = rung
-        # One measurement per batch, attached to every member request's
-        # tree below (child_at): queue_wait ends here, then assembly,
-        # the searcher's fenced device spans, and result merge.
-        rec = self.tracer.enabled
+        # The batch root owns one measurement per batch: queue_wait ends
+        # here, then assembly, the searcher's enqueue / device_wait /
+        # device_get spans, and result merge; _close_batch copies the
+        # tree into every member request's.
         bspan = NULL_SPAN
-        if rec:
+        if self.tracer.enabled:
             for r in batch:
                 r.qwait.finish()
-            t_asm0 = self.tracer.now()
-            bspan = self.tracer.request(
+            bspan = self.tracer.scoped(
                 "serve.batch", bucket="%dx%d" % bucket,
-                requests=len(batch), rows=rows, padded=qb - rows)
-        big = np.concatenate([r.queries for r in batch], axis=0)
-        padded = pad_queries(big, qb)
-        if rec:
-            t_asm1 = self.tracer.now()
+                requests=len(batch), rows=rows, padded=qb - rows,
+                seq=next(self._batch_seq))
+        with bspan.child("batch_assembly", bucket="%dx%d" % bucket,
+                         requests=len(batch)):
+            big = np.concatenate([r.queries for r in batch], axis=0)
+            padded = pad_queries(big, qb)
         # Epoch captured BEFORE the search: an extend landing mid-search
         # bumps it, and caching the pre-extend result under the new
         # epoch would be a permanently-stale hit. Under the captured
@@ -534,8 +535,7 @@ class BatchScheduler:
                 self.stats.count(rbucket, "failed")
                 if r.deadline is not None and now > r.deadline:
                     self.stats.count(rbucket, "deadline_misses")
-                r.span.finish(error=repr(err))
-            bspan.finish(error=repr(err))
+            self._close_batch(bspan, batch, error=repr(err))
             logger.warning("serve batch %sx%s failed: %r", qb, kb, err)
             return
         now = self._clock()
@@ -550,8 +550,16 @@ class BatchScheduler:
             self.stats.count(bucket, "probes_shrunk")
         quality = (self.degrade.quality_at(rung) if self.degrade is not None
                    else "full")
-        if rec:
-            t_merge0 = self.tracer.now()
+        with bspan.child("result_merge"):
+            self._merge(batch, res, epoch, rung, quality, reason, now)
+        self._close_batch(bspan, batch, degraded=res.degraded)
+        logger.trace("serve batch %sx%s: %s requests, %s rows, %s padded",
+                     qb, kb, len(batch), rows, qb - rows)
+
+    def _merge(self, batch: List[_Pending], res: SearchResult, epoch: int,
+               rung: int, quality: str, reason, now: float) -> None:
+        """Split the batch's result into its members' answers and
+        complete their tickets."""
         row = 0
         for r in batch:
             sl = slice(row, row + r.rows)
@@ -592,21 +600,19 @@ class BatchScheduler:
                 self.probe.offer(r.queries, r.k, out.indices, rbucket,
                                  epoch)
             r.ticket._complete(out)
-        if rec:
-            t_merge1 = self.tracer.now()
-            # The batch's device spans (measured once by the searcher)
-            # copy into every member's tree: a complete per-request
-            # timeline without per-request fencing.
-            device = [c for c in bspan.children
-                      if c.name in ("device_dispatch", "device_get")]
-            for r in batch:
-                r.span.child_at("batch_assembly", t_asm0, t_asm1,
-                                bucket="%dx%d" % bucket,
-                                requests=len(batch))
-                for c in device:
-                    r.span.child_at(c.name, c.start, c.end, **c.attrs)
-                r.span.child_at("result_merge", t_merge0, t_merge1)
-                r.span.finish(degraded=res.degraded)
-            bspan.finish()
-        logger.trace("serve batch %sx%s: %s requests, %s rows, %s padded",
-                     qb, kb, len(batch), rows, qb - rows)
+
+    def _close_batch(self, bspan, batch: List[_Pending], **attrs) -> None:
+        """Attach a ``gc`` child per collector pause since the last batch
+        finished, copy the batch's tree into every member's, and finish
+        them all (a complete per-request timeline without per-request
+        fencing)."""
+        if not bspan.recording:
+            return
+        for start, end, gen, collected in self.tracer.take_pauses():
+            bspan.child_at("gc", start, end, generation=gen,
+                           collected=collected)
+        for r in batch:
+            for c in bspan.children:
+                r.span.copy_child(c)
+            r.span.finish(batch=bspan.attrs["seq"], **attrs)
+        bspan.finish(**attrs)

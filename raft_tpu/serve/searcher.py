@@ -279,53 +279,6 @@ class Searcher:
             return self.health.live_mask
         return None
 
-    def _pipeline_plan(self, n_queries: int, k: int):
-        """``(resolved engine, n_chunks)`` when this searcher's next
-        dispatch runs the fused scan→merge pipeline, else None — the
-        SAME resolution the sharded entry points apply (single-sourced
-        helpers in comms/topk_merge.py), so the span annotation below
-        and the metrics scrape describe the program actually served."""
-        if self.mesh is None:
-            return None
-        if getattr(self._index, "placement", "row") == "list":
-            # Routed dispatch: the chunk count follows the PLAN's local
-            # probe width (batch-dependent), not n_probes — a host-side
-            # prediction here would annotate a program that may not
-            # have run.  The routing telemetry (obs RoutingCollector /
-            # MergeDispatchCollector participants accounting) carries
-            # the routed dispatch story instead.
-            return None
-        from raft_tpu.comms.topk_merge import (PIPELINED_ENGINES,
-                                               resolve_merge_engine,
-                                               resolve_pipeline_chunks)
-
-        axis = getattr(self._index, "axis", "data")
-        n_dev = self.mesh.shape[axis]
-        if self.kind == "brute_force":
-            from raft_tpu.neighbors.brute_force import _TILE_DB
-            from raft_tpu.util.pow2 import ceildiv
-
-            # sharded_knn pipelines over whole row tiles of its shard.
-            n_probes = None
-            shard = int(self._db.shape[0]) // n_dev
-            n_items = ceildiv(shard, min(_TILE_DB, shard))
-        else:
-            n_probes = min(self._params.n_probes,
-                           int(self._index.centers.shape[0]))
-            n_items = n_probes
-        engine = resolve_merge_engine(self.merge_engine, n_queries, k,
-                                      n_dev, n_probes=n_probes)
-        if engine not in PIPELINED_ENGINES:
-            return None
-        n_chunks = resolve_pipeline_chunks(engine, n_items, n_dev)
-        if n_chunks <= 1:
-            # The dispatch degraded to the unchunked ring
-            # (scan_merge_dispatch pipelines only at 2+ chunks) — a
-            # chunk-wave annotation here would claim a program that
-            # did not run.
-            return None
-        return engine, n_chunks
-
     def _dispatch(self, queries: np.ndarray, k: int, live,
                   valid_rows=None, params=None, suspect=None,
                   plan_cb=None):
@@ -452,11 +405,13 @@ class Searcher:
         first result by the injected clock wins (``SearchResult.hedged``).
 
         ``span`` (an :class:`raft_tpu.obs.trace.Span`) attaches the two
-        device-boundary child spans — ``device_dispatch`` (fenced with
-        ``jax.block_until_ready`` so the measured interval is real
-        device time, not async-dispatch enqueue time) and
-        ``device_get`` (the replicated-result pull).  With no recording
-        span the fence is SKIPPED: tracing off must not serialize the
+        device-boundary child spans — ``device_dispatch`` and
+        ``device_get`` (the replicated-result pull).  ``device_dispatch``
+        holds two measured children: ``enqueue`` (the family dispatch:
+        Python glue, the query upload and the launch of the compiled
+        program) and ``device_wait`` (``jax.block_until_ready``, so the
+        span closes when the device finishes).  With no recording span
+        the fence is SKIPPED: tracing off must not serialize the
         dispatch pipeline, and no span machinery touches the traced
         program either way (the compiled program is identical — the
         sanitized lane proves it)."""
@@ -498,11 +453,12 @@ class Searcher:
                       engine=self.merge_engine,
                       sharded=self.mesh is not None) as dd:
             t0 = self._monotonic()
-            if self.retry is not None:
-                out = with_retry(attempt, self.retry, sleep=self._sleep,
-                                 monotonic=self._monotonic)
-            else:
-                out = attempt()
+            with dd.child("enqueue"):
+                if self.retry is not None:
+                    out = with_retry(attempt, self.retry, sleep=self._sleep,
+                                     monotonic=self._monotonic)
+                else:
+                    out = attempt()
             if track and plan_box:
                 ranks, elapsed = self._after_dispatch(plan_box[-1], t0)
                 if self.hedge is not None and self.health is not None:
@@ -514,30 +470,9 @@ class Searcher:
             if dd.recording:
                 # Fence so the span closes when the DEVICE finishes, not
                 # when XLA accepted the async dispatch — device time is
-                # real, host time stays separate.  jax.profiler picks up
-                # the same boundary for its own timeline.
-                with jax.profiler.TraceAnnotation("raft.device_fence"):
+                # real, host time stays separate.
+                with dd.child("device_wait"):
                     jax.block_until_ready(out)
-                plan = self._pipeline_plan(q.shape[0], k)
-                if plan is not None:
-                    # One child span per pipeline chunk WAVE (the fused
-                    # scan→merge pipeline, docs/sharded_search.md): the
-                    # waves run inside one compiled program, so the
-                    # host splits the fenced device window evenly —
-                    # estimated=True marks the boundaries as synthetic
-                    # (the HLO-level truth is the
-                    # "raft.pipeline_chunk" named_scope tags in the
-                    # profiler timeline).
-                    engine, n_chunks = plan
-                    dd.annotate(pipeline_chunks=n_chunks)
-                    t1 = dd.now()
-                    step = (t1 - dd.start) / max(n_chunks, 1)
-                    for c in range(n_chunks):
-                        dd.child_at("pipeline_chunk",
-                                    dd.start + c * step,
-                                    dd.start + (c + 1) * step,
-                                    chunk=c, engine=engine,
-                                    estimated=True)
         # jax.device_get, not np.asarray: the result pull is the DECLARED
         # host boundary of the hot path, so it stays legal under the
         # sanitizer lane's jax.transfer_guard("disallow") (tests/conftest)

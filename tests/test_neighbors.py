@@ -176,6 +176,24 @@ class TestIvfFlat:
         _, truth = _naive_knn(q, full, 5)
         assert _recall(np.asarray(i), truth) > 0.99
 
+    def test_cells_search_names_its_stages(self):
+        """The packed-cells program carries a named scope per stage, so
+        a profiler trace names each device operation by the stage that
+        ran it."""
+        import jax.numpy as jnp
+
+        from raft_tpu.neighbors.ivf_flat import _cells_search
+
+        f32 = jnp.float32
+        args = (jnp.zeros((16, 8), f32), jnp.zeros((4, 8), f32),
+                jnp.zeros((4, 128, 8), f32), jnp.zeros((4, 128), jnp.int32),
+                jnp.zeros((4,), jnp.int32))
+        text = _cells_search.lower(*args, 2, 5, True, False, 8, False,
+                                   True).as_text(debug_info=True)
+        for stage in ("coarse_probe", "cells_invert", "cells_scan",
+                      "id_gather", "route_select"):
+            assert "ivf_flat.%s/" % stage in text, stage
+
     def test_extend_in_place_o_n_new(self, rng):
         """extend() appends at O(n_new): when the new rows fit the existing
         capacity, the storage buffer is donated and aliased (no repack),

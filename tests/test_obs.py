@@ -10,6 +10,9 @@ sanitized-lane cases showing instrumented steady-state serving compiles
 nothing and trips no implicit transfer.
 """
 
+import contextlib
+import gc
+import glob
 import json
 import os
 import threading
@@ -83,33 +86,53 @@ class _StepClock:
         return self.t
 
 
+@contextlib.contextmanager
+def _gc_off():
+    """Automatic collections off for the block: an enabled tracer
+    records each one, reading the clock (a _StepClock advances on every
+    read) and adding a ``gc`` span."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
 def _golden_trace() -> Tracer:
     """The deterministic span scenario both golden tests export: one
-    request root with the full serving child set, one batch root."""
-    tracer = Tracer(clock=_StepClock(), max_traces=16)
-    root = tracer.request("serve.request", rows=3, k=5, bucket="4x8",
-                          seq=1)
-    with root.child("cache_lookup"):
-        pass
-    qw = root.child("queue_wait")
-    qw.finish()
-    root.child_at("batch_assembly", 0.005, 0.006, bucket="4x8",
-                  requests=2)
-    dd = root.child_at("device_dispatch", 0.006, 0.009, kind="brute_force",
-                       engine="auto", sharded=True, pipeline_chunks=2)
-    # Chunk waves of the fused scan→merge pipeline (ISSUE 14): evenly
-    # split synthetic intervals under the fenced dispatch window, the
-    # shape Searcher.search attaches when the pipelined engine serves.
-    dd.child_at("pipeline_chunk", 0.006, 0.0075, chunk=0,
-                engine="pipelined", estimated=True)
-    dd.child_at("pipeline_chunk", 0.0075, 0.009, chunk=1,
-                engine="pipelined", estimated=True)
-    root.child_at("device_get", 0.009, 0.010)
-    root.child_at("result_merge", 0.010, 0.011)
-    root.finish(degraded=False)
-    batch = tracer.request("serve.batch", bucket="4x8", requests=2,
-                           rows=3, padded=1)
-    batch.finish()
+    batch root owning the batch's spans (the searcher's ``enqueue`` and
+    ``device_wait`` under ``device_dispatch``, one collector pause),
+    copied into its one member request's tree — the shape
+    BatchScheduler._dispatch builds."""
+    with _gc_off():
+        tracer = Tracer(clock=_StepClock(), max_traces=16)
+        root = tracer.request("serve.request", rows=3, k=5, bucket="4x8",
+                              seq=1)
+        with root.child("cache_lookup"):
+            pass
+        qw = root.child("queue_wait")
+        qw.finish()
+        batch = tracer.scoped("serve.batch", bucket="4x8", requests=1,
+                              rows=3, padded=1, seq=0)
+        with batch.child("batch_assembly", bucket="4x8", requests=1):
+            pass
+        with batch.child("device_dispatch", kind="brute_force",
+                         engine="auto", sharded=True) as dd:
+            with dd.child("enqueue"):
+                pass
+            with dd.child("device_wait"):
+                pass
+        with batch.child("device_get"):
+            pass
+        with batch.child("result_merge"):
+            pass
+        batch.child_at("gc", 0.0125, 0.0135, generation=0, collected=7)
+        for c in batch.children:
+            root.copy_child(c)
+        root.finish(batch=0, degraded=False)
+        batch.finish(degraded=False)
     return tracer
 
 
@@ -199,6 +222,41 @@ class TestSpan:
         a, b = tracer.request("a"), tracer.request("b")
         assert a.tid != b.tid
 
+    def test_enabled_tracer_records_collector_pauses(self):
+        with _gc_off():
+            tracer = Tracer(clock=_StepClock())
+            assert tracer.take_pauses() == []
+            gc.collect(1)
+            gc.collect()
+            pauses = tracer.take_pauses()
+        assert [p[2] for p in pauses] == [1, 2]       # generation
+        assert all(p[0] < p[1] for p in pauses)       # on its clock
+        assert tracer.take_pauses() == []             # drained
+        tracer.close()
+
+    @pytest.mark.parametrize("how", ["disabled", "closed", "collected"])
+    def test_gc_callbacks_unchanged(self, how):
+        """A disabled tracer registers no gc hook; an enabled one's hook
+        goes when it is closed or collected (the hook holds it only
+        weakly, so a tracer with published spans — a reference cycle
+        through their sink — still dies)."""
+        gc.collect()                    # earlier tests' dead tracers
+        before = list(gc.callbacks)
+        if how == "disabled":
+            Tracer(enabled=False)
+            assert gc.callbacks == before
+            return
+        tracer = Tracer()
+        tracer.request("r").finish()
+        assert len(gc.callbacks) == len(before) + 1
+        if how == "closed":
+            tracer.close()
+            tracer.close()                            # idempotent
+        else:
+            del tracer
+            gc.collect()
+        assert gc.callbacks == before
+
 
 # ---------------------------------------------------------------------------
 # Golden exports (bit-stable: injected clock + deterministic ordering)
@@ -222,17 +280,25 @@ class TestGoldenExports:
                    for e in events)
         root = events[0]
         assert root["name"] == "serve.request"
+        assert root["args"]["batch"] == 0
         kids = [e["name"] for e in events if e["tid"] == root["tid"]][1:]
         assert kids == ["cache_lookup", "queue_wait", "batch_assembly",
-                        "device_dispatch", "pipeline_chunk",
-                        "pipeline_chunk", "device_get", "result_merge"]
+                        "device_dispatch", "enqueue", "device_wait",
+                        "device_get", "result_merge", "gc"]
+        batch = [e for e in events if e["name"] == "serve.batch"][0]
+        # The member's copies carry the batch's own intervals.
+        own = [(e["name"], e["ts"], e["dur"]) for e in events
+               if e["tid"] == batch["tid"]][1:]
+        assert own == [(e["name"], e["ts"], e["dur"]) for e in events
+                       if e["tid"] == root["tid"]][3:]
 
     def test_json_export_roundtrip(self):
         tracer = _golden_trace()
         trees = json.loads(tracer.to_json())
         assert len(trees) == 2
         assert trees[0]["attrs"]["bucket"] == "4x8"
-        assert len(trees[0]["children"]) == 6
+        assert len(trees[0]["children"]) == 7
+        assert trees[1]["attrs"]["seq"] == trees[0]["attrs"]["batch"]
 
     def test_prometheus_golden(self):
         _check_golden("obs_scrape.prom",
@@ -625,16 +691,18 @@ class TestServeTracing:
         assert t.done
         return tracer, sched, q
 
-    def test_pipeline_chunk_wave_spans(self, db, mesh4):
-        """A pipelined sharded searcher attaches one pipeline_chunk
-        child per chunk wave under the fenced device_dispatch span —
-        an even synthetic split of the measured device window, marked
-        estimated — plus the chunk-count attribute (ISSUE 14 obs
-        satellite); non-pipelined searchers attach none."""
+    @staticmethod
+    def _by_name(span):
+        return {c.name: c for c in span.children}
+
+    def test_sharded_dispatch_measures_enqueue_and_device_wait(self, db,
+                                                                mesh4):
+        """A sharded (pipelined) searcher's device_dispatch holds the
+        two measured children, enqueue then device_wait, and nothing
+        else."""
         from raft_tpu.parallel import sharded_ivf_flat_build
 
-        clock = _StepClock()
-        tracer = Tracer(clock=clock)
+        tracer = Tracer(clock=_StepClock())
         params = ivf_flat.IndexParams(n_lists=8, kmeans_n_iters=2)
         index = sharded_ivf_flat_build(mesh4, params, db)
         s = Searcher.ivf_flat(index, ivf_flat.SearchParams(n_probes=8),
@@ -644,24 +712,136 @@ class TestServeTracing:
         root = tracer.request("serve.request")
         s.search(q, 5, span=root)
         root.finish()
-        dd = [c for c in root.children if c.name == "device_dispatch"][0]
-        waves = [c for c in dd.children if c.name == "pipeline_chunk"]
-        assert dd.attrs["pipeline_chunks"] == len(waves) == 2  # 8//4
-        assert [w.attrs["chunk"] for w in waves] == [0, 1]
-        assert all(w.attrs["estimated"] for w in waves)
-        assert waves[0].start == dd.start
-        assert waves[0].end == waves[1].start     # contiguous partition
-        assert waves[-1].end <= dd.end
+        dd = self._by_name(root)["device_dispatch"]
+        assert dd.attrs == {"kind": "ivf_flat", "engine": "pipelined",
+                            "sharded": True}
+        assert [c.name for c in dd.children] == ["enqueue", "device_wait"]
+        enq, wait = dd.children
+        assert dd.start <= enq.start < enq.end <= wait.start < wait.end \
+            <= dd.end
 
-        s2 = Searcher.ivf_flat(index, ivf_flat.SearchParams(n_probes=8),
-                               mesh=mesh4, merge_engine="ring")
-        root2 = tracer.request("serve.request")
-        s2.search(q, 5, span=root2)
-        root2.finish()
-        dd2 = [c for c in root2.children
-               if c.name == "device_dispatch"][0]
-        assert not [c for c in dd2.children
-                    if c.name == "pipeline_chunk"]
+    def test_device_dispatch_enqueue_then_device_wait(self, db, mesh4):
+        """Under the scheduler, device_dispatch holds enqueue then
+        device_wait: nested inside it, in that order, not overlapping."""
+        tracer, sched, _ = self._serve(db, mesh4)
+        batch = [sp for sp in tracer.take() if sp.name == "serve.batch"][0]
+        dd = self._by_name(batch)["device_dispatch"]
+        assert [c.name for c in dd.children] == ["enqueue", "device_wait"]
+        enq, wait = dd.children
+        assert dd.start < enq.start < enq.end < wait.start < wait.end \
+            < dd.end
+        sched.close()
+
+    def test_batch_root_owns_its_tree(self, db, mesh4):
+        """The serve.batch root owns assembly, dispatch, pull and merge
+        as its own children and carries ``seq``; every member's tree
+        carries the same intervals (subtrees included) plus
+        ``batch=<seq>``."""
+        with _gc_off():
+            clock = _StepClock()
+            tracer = Tracer(clock=clock)
+            s = Searcher.brute_force(db, mesh=mesh4)
+            sched = BatchScheduler(
+                s, BucketGrid.pow2(8, k_grid=(5,)),
+                BatchPolicy(max_batch=8, max_wait=0.0), clock=clock,
+                tracer=tracer)
+            rng = np.random.default_rng(4)
+            for _ in range(2):
+                tickets = [sched.submit(rng.normal(size=(n, DIM)).astype(
+                    np.float32), 5) for n in (3, 2)]
+                sched.run_until_idle()
+        spans = tracer.take()
+        batches = [sp for sp in spans if sp.name == "serve.batch"]
+        assert [b.attrs["seq"] for b in batches] == [0, 1]
+        batch = batches[1]
+        assert [c.name for c in batch.children] == [
+            "batch_assembly", "device_dispatch", "device_get",
+            "result_merge"]
+        for c in batch.children:
+            assert batch.start <= c.start <= c.end <= batch.end
+
+        def shape(sp):
+            return (sp.name, sp.start, sp.end, sp.attrs,
+                    [shape(c) for c in sp.children])
+
+        members = [sp for sp in spans if sp.name == "serve.request"
+                   and sp.attrs["batch"] == 1]
+        assert sorted(m.attrs["seq"] for m in members) == \
+            sorted(t.seq for t in tickets)
+        for m in members:
+            assert m.children[0].name == "queue_wait"
+            assert [shape(c) for c in m.children[1:]] == \
+                [shape(c) for c in batch.children]
+        sched.close()
+
+    def test_gc_pause_is_a_child_of_the_next_batch(self, db, mesh4):
+        """A collection forced between two batches is a ``gc`` child,
+        with its generation, of the second batch and of its members,
+        not of the first."""
+        with _gc_off():
+            tracer = Tracer()
+            s = Searcher.brute_force(db, mesh=mesh4)
+            sched = BatchScheduler(
+                s, BucketGrid.pow2(8, k_grid=(5,)),
+                BatchPolicy(max_batch=8, max_wait=0.0), tracer=tracer)
+            q = np.random.default_rng(5).normal(
+                size=(2, DIM)).astype(np.float32)
+            first = sched.submit(q, 5)
+            sched.run_until_idle()
+            gc.collect()
+            second = sched.submit(q, 5)
+            sched.run_until_idle()
+        batches = [sp for sp in tracer.take() if sp.name == "serve.batch"]
+        assert "gc" not in self._by_name(batches[0])
+        assert "gc" not in self._by_name(first.span)
+        for sp in (batches[1], second.span):
+            pauses = [c for c in sp.children if c.name == "gc"]
+            assert [p.attrs["generation"] for p in pauses] == [2]
+            assert pauses[0].attrs["collected"] >= 0
+            assert batches[0].end <= pauses[0].start <= pauses[0].end \
+                <= batches[1].start
+        sched.close()
+
+    def test_live_spans_enter_profiler_ranges(self, db, mesh4, tmp_path):
+        """Under jax.profiler, the batch root, each of its live spans and
+        each collector pause land in the trace as host ranges
+        ``raft_tpu::serve.<name>``, nested as the spans are."""
+        tracer = Tracer()
+        s = Searcher.brute_force(db, mesh=mesh4)
+        sched = BatchScheduler(s, BucketGrid.pow2(8, k_grid=(5,)),
+                               BatchPolicy(max_batch=8, max_wait=0.0),
+                               tracer=tracer)
+        q = np.random.default_rng(6).normal(
+            size=(2, DIM)).astype(np.float32)
+        sched.submit(q, 5)
+        sched.run_until_idle()                      # compiles outside
+        with jax.profiler.trace(str(tmp_path)):
+            sched.submit(q, 5)
+            gc.collect()
+            sched.run_until_idle()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        ranges = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("raft_tpu::serve."):
+                        ranges.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+        names = ["batch", "batch_assembly", "device_dispatch", "enqueue",
+                 "device_wait", "device_get", "result_merge"]
+        assert {"raft_tpu::serve." + n for n in names + ["gc"]} <= \
+            set(ranges)
+        (b0, b1), = ranges["raft_tpu::serve.batch"]
+        for n in names[1:]:
+            (s0, s1), = ranges["raft_tpu::serve." + n]
+            assert b0 <= s0 <= s1 <= b1
+        (d0, d1), = ranges["raft_tpu::serve.device_dispatch"]
+        for n in ("enqueue", "device_wait"):
+            (s0, s1), = ranges["raft_tpu::serve." + n]
+            assert d0 <= s0 <= s1 <= d1
+        sched.close()
+        tracer.close()
 
     def test_complete_span_tree_per_request(self, db, mesh4):
         tracer, sched, _ = self._serve(db, mesh4)
@@ -669,7 +849,7 @@ class TestServeTracing:
         roots = [s for s in spans if s.name == "serve.request"]
         assert len(roots) == 1
         root = roots[0]
-        names = [c.name for c in root.children]
+        names = [c.name for c in root.children if c.name != "gc"]
         assert names == ["queue_wait", "batch_assembly",
                          "device_dispatch", "device_get", "result_merge"]
         # Every span closed, monotonic on the injected clock, children
@@ -741,7 +921,16 @@ class TestServeTracing:
         assert root.end is not None and "error" in root.attrs
         sched.close()
 
-    def test_tracer_off_is_default_and_inert(self, db, mesh4):
+    def test_tracer_off_is_default_and_inert(self, db, mesh4, monkeypatch):
+        """Tracing off: no spans, no fence, no profiler range from obs,
+        no gc hook."""
+        import raft_tpu.obs.trace as obs_trace
+
+        ranges, fences = [], []
+        monkeypatch.setattr(obs_trace, "push_range", ranges.append)
+        monkeypatch.setattr(jax, "block_until_ready", fences.append)
+        gc.collect()                    # earlier tests' dead tracers
+        before = list(gc.callbacks)
         s = Searcher.brute_force(db, mesh=mesh4)
         grid = BucketGrid.pow2(8, k_grid=(5,))
         sched = BatchScheduler(s, grid,
@@ -749,9 +938,13 @@ class TestServeTracing:
         assert sched.tracer is NULL_TRACER
         t = sched.submit(np.random.default_rng(2).normal(
             size=(3, DIM)).astype(np.float32), 5)
+        gc.collect()
         sched.run_until_idle()
         assert t.done and t.span is NULL_SPAN
         assert NULL_TRACER.pending == 0
+        assert fences == [] and gc.callbacks == before
+        # Only the pauses of other, still enabled tracers of the process.
+        assert set(ranges) <= {"serve.gc"}
         sched.close()
 
 
@@ -975,9 +1168,12 @@ def test_instrumented_serving_steady_state(mesh4, db, sanitizer_lane):
     reg.prometheus_text()
     sanitizer_lane.mark_steady()
 
-    tickets = [sched.submit(rng.normal(size=(n, DIM)).astype(np.float32),
-                            5) for n in (1, 4, 8, 2)]
-    sched.run_until_idle()
+    tickets = []
+    for n in (1, 4, 8, 2):
+        tickets.append(sched.submit(
+            rng.normal(size=(n, DIM)).astype(np.float32), 5))
+        gc.collect()                        # a pause in every batch
+        sched.run_until_idle()
     assert all(t.done for t in tickets)
     scanned = probe.run_pending()           # shadow exact scans
     text = reg.prometheus_text()            # scrape mid-everything
@@ -985,10 +1181,18 @@ def test_instrumented_serving_steady_state(mesh4, db, sanitizer_lane):
     assert scanned >= 1 and probe.recall() == 1.0   # brute force: exact
     spans = tracer.take()
     assert any(s.name == "serve.request" and
-               [c.name for c in s.children][-1] == "result_merge"
-               for s in spans)
+               [c.name for c in s.children if c.name != "gc"][-1]
+               == "result_merge" for s in spans)
+    # The recording tracer's whole tree was there: fenced device spans
+    # and collector pauses, with nothing recompiled or transferred.
+    steady = [s for s in spans if s.name == "serve.batch"][1:]
+    assert steady and all(
+        [c.name for c in s.children[1].children] == ["enqueue",
+                                                     "device_wait"]
+        and any(c.name == "gc" for c in s.children) for s in steady)
     assert sanitizer_lane.steady_compiles == 0
     sched.close()
+    tracer.close()
 
 
 @pytest.mark.sanitized

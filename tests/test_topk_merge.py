@@ -244,19 +244,6 @@ class TestShardedPipelinedConsumers:
         np.testing.assert_array_equal(np.asarray(bd), np.asarray(d))
         np.testing.assert_array_equal(np.asarray(bi), np.asarray(i))
 
-    def test_searcher_pipeline_plan_counts_row_tiles(self, rng):
-        """The Searcher's chunk annotation follows sharded_knn's split
-        over whole scan tiles: a shard of one tile runs unchunked."""
-        from raft_tpu.serve import Searcher
-
-        mesh = _mesh(8)
-        small = rng.normal(size=(1024, 16)).astype(np.float32)
-        s = Searcher.brute_force(small, mesh=mesh, merge_engine="pipelined")
-        assert s._pipeline_plan(32, 10) is None
-        big = np.zeros((8 * 16 * 8192, 16), np.float32)  # 16 tiles a shard
-        s = Searcher.brute_force(big, mesh=mesh, merge_engine="pipelined")
-        assert s._pipeline_plan(32, 10) == ("pipelined", 4)
-
     @pytest.mark.parametrize("tier", ["scan", "bucketed"])
     @pytest.mark.parametrize("n_probes,chunks", [(7, 3), (8, 0), (5, 2)])
     def test_sharded_ivf_flat_pipelined_grid(self, rng, tier, n_probes,
