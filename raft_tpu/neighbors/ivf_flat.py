@@ -851,19 +851,46 @@ def _invert_probe_map_cells(probe_ids, n_lists: int, qrows: int):
     return cell_list, bucket, (cell, slot, order)
 
 
-def _route_candidates_cells(bd_, gi, route, q: int, p: int):
+def _route_candidates_cells(bd_, payload, route, q: int, p: int):
     """Send each packed cell slot's top-kk candidates back to its query:
-    (q, p·kk) candidate rows for the final select_k (the cells analog of
-    :func:`_route_candidates`; nothing is dropped, so there is no keep
-    mask)."""
+    (q, p·kk) distance/payload candidate rows for the final select_k (the
+    cells analog of :func:`_route_candidates`; nothing is dropped, so
+    there is no keep mask)."""
     cell, slot, order = route
     kk = bd_.shape[2]
     cd = bd_[cell, slot]                                       # (p·q, kk)
-    ci = gi[cell, slot]
+    ci = payload[cell, slot]
     inv = jnp.argsort(order)
     cd = cd[inv].reshape(p, q, kk).transpose(1, 0, 2).reshape(q, p * kk)
     ci = ci[inv].reshape(p, q, kk).transpose(1, 0, 2).reshape(q, p * kk)
     return cd, ci
+
+
+def _select_cells_ids(bd_, bi_, cell_list, indices, route, q: int,
+                      p: int, k: int, scope: str):
+    """The per-query merge of a cells kernel's output: route each cell
+    slot's top-kk candidates back to its query, keep the best k, and only
+    then look up their ids. Candidates travel as flat slot positions
+    (``list · cap + slot``, row-major into ``indices``), so the id table
+    is read for the q·k winners rather than for all max_cells·qrows·kk
+    candidates. select_k ranks by value and breaks ties by position,
+    never by the payload, so the answer is the one an id-carrying merge
+    gives. ``bd_`` / ``bi_`` are the kernel's min-order distances and
+    local slots (-1: no candidate); ``scope`` prefixes the stages' named
+    scopes. Returns min-order ``(q, k)`` distances and ids (-1: none)."""
+    n_lists, cap = indices.shape
+    expects(n_lists * cap < 2 ** 31, "slot positions must fit in int32")
+    with jax.named_scope(scope + ".route_select"):
+        pos = jnp.where(bi_ < 0, -1,
+                        jnp.maximum(cell_list, 0)[:, None, None] * cap + bi_)
+        cd, cpos = _route_candidates_cells(bd_, pos, route, q, p)
+        best_d, best_pos = select_k(cd, k, select_min=True, indices=cpos)
+    with jax.named_scope(scope + ".id_gather"):
+        # Two-axis indexing: a flat view of the table would make XLA
+        # relayout all of it on the TPU for every batch.
+        safe = jnp.maximum(best_pos, 0)
+        best_i = jnp.where(best_pos < 0, -1, indices[safe // cap, safe % cap])
+    return best_d, best_i
 
 
 def _route_candidates(bd_, gi, route, q: int, p: int, bucket_cap: int,
@@ -946,17 +973,11 @@ def _cells_scan_probes(Q, probe_ids, data, indices, list_sizes, k: int,
                                    l2=inner_is_l2,
                                    bf16=data.dtype == jnp.bfloat16,
                                    qsplit=qsplit, interpret=interpret)
-    with jax.named_scope("ivf_flat.id_gather"):
-        gi = indices[jnp.maximum(cell_list, 0)[:, None, None],
-                     jnp.maximum(bi_, 0)]
-        gi = jnp.where(bi_ < 0, -1, gi)
-    with jax.named_scope("ivf_flat.route_select"):
-        # The kernel reports min-selection order (ip scores negated).
-        cd, ci = _route_candidates_cells(bd_, gi, route, q,
-                                         probe_ids.shape[1])
-        best_d, best_i = select_k(cd, k, select_min=True, indices=ci)
-        if not inner_is_l2:
-            best_d = -best_d
+    best_d, best_i = _select_cells_ids(bd_, bi_, cell_list, indices, route,
+                                       q, probe_ids.shape[1], k, "ivf_flat")
+    # The kernel reports min-selection order (ip scores negated).
+    if not inner_is_l2:
+        best_d = -best_d
     return best_d, best_i
 
 

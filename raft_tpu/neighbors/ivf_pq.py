@@ -64,7 +64,7 @@ from raft_tpu.neighbors.ivf_flat import (
     _pad_deleted,
     _pick_engine,
     _route_candidates,
-    _route_candidates_cells,
+    _select_cells_ids,
     _track_next_id,
 )
 from raft_tpu.random.rng_state import RngState
@@ -694,13 +694,10 @@ def _compressed_scan_probes(rotq_p, probe_ids, codesT, abs_lo, abs_hi,
                         precision=lax.Precision.HIGHEST)  # (q, n_lists)
         qc_pair = qc[jnp.maximum(bucket, 0), safe_cl[:, None]]
         bd_ = bd_ - qc_pair[:, :, None]
-    gi = indices[safe_cl[:, None, None], jnp.maximum(bi_, 0)]
-    gi = jnp.where(bi_ < 0, -1, gi)
     # The kernel reports min-selection order for both metrics (negated
     # inner products); undo the negation after the final merge.
-    cd, ci = _route_candidates_cells(bd_, gi, route, q,
-                                     probe_ids.shape[1])
-    best_d, best_i = select_k(cd, k, select_min=True, indices=ci)
+    best_d, best_i = _select_cells_ids(bd_, bi_, cell_list, indices, route,
+                                       q, probe_ids.shape[1], k, "ivf_pq")
     if is_ip:
         best_d = -best_d
     return best_d, best_i

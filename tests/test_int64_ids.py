@@ -53,10 +53,10 @@ d, i = ivf_pq.search(ivf_pq.SearchParams(n_probes=8, engine="scan"),
                      pidx, q, 5)
 assert i.dtype == jnp.int64, i.dtype
 
-# int64 ids through the packed-cells / compressed tiers (the id payload
-# gathers: indices[cell_list][bi], route, select_k payload — every hop
-# must keep the 64-bit dtype; engine="bucketed" forces the kernels in
-# interpret mode on CPU)
+# int64 ids through the packed-cells / compressed tiers (the merge carries
+# int32 slot positions; the winners' ids, gathered after it, must keep
+# the 64-bit dtype; engine="bucketed" forces the kernels in interpret
+# mode on CPU)
 d, ic = ivf_flat.search(ivf_flat.SearchParams(n_probes=8,
                                               engine="bucketed"), idx, q, 5)
 assert ic.dtype == jnp.int64, ic.dtype
