@@ -1,5 +1,5 @@
-"""The device's ``peak_bytes_in_use`` after the window (build included),
-in GB (1e9 bytes)."""
+"""The fullest chip's ``peak_bytes_in_use`` after the window (build
+included), in GB (1e9 bytes)."""
 
 
 def read(run):

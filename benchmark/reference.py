@@ -9,7 +9,9 @@ products split into bfloat16 passes by hand ("high": three passes,
 ``pair_distances`` gives the squared distance of given (query, row) pairs
 in the difference form, which has no cancellation: the yardstick a served
 distance is held to. Both pad the last query tile to a whole tile, so
-that every number of queries runs the same compiled programs.
+that every number of queries runs the same compiled programs, and both
+take a corpus that one device holds or that is row-sharded over several
+(one too large for a chip): a shard's rows are read on its own device.
 """
 
 from __future__ import annotations
@@ -70,25 +72,45 @@ def _merge(d0, i0, d1, i1, k):
     return -neg, jnp.take_along_axis(i, pos, axis=1)
 
 
+def _shards(X) -> list:
+    """``[(first row, that device's rows, device)]`` of ``X`` in row
+    order: one entry for a one-device array, one per shard for an array
+    sharded by rows (a shard held on several devices counts once)."""
+    parts = {}
+    for sh in X.addressable_shards:
+        if sh.data.shape[1:] != X.shape[1:]:
+            raise ValueError("the corpus may be sharded by rows only")
+        parts.setdefault(sh.index[0].start or 0, (sh.data, sh.device))
+    return [(first, x, dev) for first, (x, dev) in sorted(parts.items())]
+
+
 def exact_knn(X, queries: np.ndarray, k: int, tile: int = 125_000,
               q_tile: int = 1024, precision: str = "highest"):
     """Exact squared-L2 top-k ``(distances, ids)`` of ``queries`` over
-    the rows of ``X``, as host arrays, best first."""
-    dev = next(iter(X.devices()))
-    tiles = [(jnp.int32(s), X[s:s + tile])
-             for s in range(0, X.shape[0], tile)]
-    out_d, out_i = [], []
+    the rows of ``X``, as host arrays, best first. ``X`` is one device's
+    array or row-sharded over several: each corpus tile is scored on the
+    device that holds it, every tile of every query tile is dispatched
+    before a result is read, and the tiles are merged in row order on the
+    first shard's device. Where ``tile`` divides each shard's rows the
+    tiles are the one-device tiles, and the answer is the one-device
+    answer, bit for bit."""
+    tiles = [(dev, jnp.int32(first + s), x[s:s + tile])
+             for first, x, dev in _shards(X)
+             for s in range(0, x.shape[0], tile)]
+    home = tiles[0][0]
+    out = []
     for qs in range(0, len(queries), q_tile):
-        q = jax.device_put(_tile(queries, qs, q_tile).astype(np.float32),
-                           dev)
-        best = None
-        for base, x in tiles:
-            d, i = _exact_tile(q, x, base, k, precision)
-            best = (d, i) if best is None else _merge(*best, d, i, k)
-        out_d.append(np.asarray(best[0]))
-        out_i.append(np.asarray(best[1]))
+        q = _tile(queries, qs, q_tile).astype(np.float32)
+        on = {dev: jax.device_put(q, dev) for dev in {t[0] for t in tiles}}
+        parts = [_exact_tile(on[dev], x, base, k, precision)
+                 for dev, base, x in tiles]
+        best = parts[0]
+        for d, i in parts[1:]:
+            best = _merge(*best, *jax.device_put((d, i), home), k)
+        out.append(best)
     n = len(queries)
-    return np.concatenate(out_d)[:n], np.concatenate(out_i)[:n]
+    return (np.concatenate([np.asarray(d) for d, _ in out])[:n],
+            np.concatenate([np.asarray(i) for _, i in out])[:n])
 
 
 @jax.jit
@@ -101,13 +123,22 @@ def pair_distances(X, queries: np.ndarray, ids: np.ndarray,
                    q_tile: int = 1024) -> np.ndarray:
     """Squared L2 distance of each ``queries[r]`` to each row
     ``X[ids[r, j]]`` (ids clipped into range; the caller flags the ones
-    that were not)."""
-    dev = next(iter(X.devices()))
+    that were not). Of a row-sharded ``X``, each row is gathered on the
+    device that holds it; every tile is dispatched before one is read."""
     n = X.shape[0]
-    out = []
+    shards = _shards(X)
+    parts = []
     for s in range(0, len(queries), q_tile):
-        q = jax.device_put(_tile(queries, s, q_tile).astype(np.float32), dev)
-        i = jax.device_put(np.clip(_tile(ids, s, q_tile), 0, n - 1)
-                           .astype(np.int32), dev)
-        out.append(np.asarray(_pair_tile(q, X[i])))
-    return np.concatenate(out)[:len(queries)]
+        q = _tile(queries, s, q_tile).astype(np.float32)
+        i = np.clip(_tile(ids, s, q_tile), 0, n - 1).astype(np.int32)
+        for first, x, dev in shards:
+            own = (i >= first) & (i < first + x.shape[0])
+            local = np.where(own, i - first, 0).astype(np.int32)
+            parts.append((own, _pair_tile(jax.device_put(q, dev),
+                                          x[jax.device_put(local, dev)])))
+    out = np.zeros((len(parts) // len(shards) * q_tile,) + ids.shape[1:],
+                   np.float32)
+    for t, (own, d) in enumerate(parts):
+        rows = out[t // len(shards) * q_tile:][:q_tile]
+        rows[own] = np.asarray(d)[own]
+    return out[:len(queries)]

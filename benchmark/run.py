@@ -9,11 +9,11 @@ serves it through ``Searcher`` and ``BatchScheduler`` (no result cache,
 no deadlines, no degradation ladder, no hedging), warms the buckets the
 traffic file names (and drives the traffic for its ``warm_drive_s``), then
 drives the traffic for ``--seconds``; ``--seed`` draws the traffic. After
-the window it reads the device's peak memory, frees the program's state
-and checks every answer of the window against the exact reference
-(``benchmark/correct.py``). With ``--trace 1`` the window runs under the
-profiler and with the program's request spans on, and the line carries
-the per-layer metrics instead of the end-to-end ones.
+the window it reads the peak memory of each of the cell's chips, frees
+the program's state and checks every answer of the window against the
+exact reference (``benchmark/correct.py``). With ``--trace 1`` the window
+runs under the profiler and with the program's request spans on, and the
+line carries the per-layer metrics instead of the end-to-end ones.
 
 Stdout's last line is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (``breakdown`` when traced) and, last,
@@ -67,7 +67,8 @@ class Run:
         self.start = self.end = 0.0
         self.setup_s = 0.0
         self.timings: dict = {}
-        self.peak_bytes = None
+        self.peak_bytes = None      # the fullest chip's
+        self.peaks: list = []       # each chip's, in device order
         self.recall = None
         self.stats: dict = {}
         self.trace = None
@@ -225,8 +226,10 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
                     mark("bench.window"):
                 run.requests, run.start, run.end = loop.run(
                     sched, pool, traffic, seed, seconds, mark=mark)
-        stats = jax.devices()[0].memory_stats() or {}
-        run.peak_bytes = stats.get("peak_bytes_in_use")
+        run.peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                     for d in jax.devices()[:int(cell["chips"])]]
+        run.peak_bytes = max((p for p in run.peaks if p is not None),
+                             default=None)
         for r in run.requests:
             if r.ticket is not None and r.ticket.done and r.error is None:
                 try:
@@ -291,7 +294,8 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
     devices = jax.devices()
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": run.peak_bytes}
+              "memory_peak_bytes": run.peak_bytes,
+              "memory_peak_bytes_by_device": run.peaks}
     line = {"correct": bool(answered) and correct.passed(checked),
             "attempted": len(run.requests),
             "failed": len(run.requests) - len(answered),

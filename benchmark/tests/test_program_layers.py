@@ -114,3 +114,17 @@ def test_unanswered_requests_are_left_out():
     run.requests[-2].done = math.nan      # the third batch is unanswered
     assert _read("turnaround_ms.batch", run) == pytest.approx(4.5)
     assert _read("gc_ms.batch", run) == pytest.approx(0.2)
+
+
+def test_cells_scan_ms_is_per_batch_and_device():
+    read = cells.load_module("layers", "cells_scan_ms.batch").read
+    run = _run()
+    run.trace = {"scopes": {
+        "/device:TPU:0": {"ivf_flat.cells_scan": 0.030, "": 0.002},
+        "/device:TPU:1": {"ivf_flat.cells_scan": 0.036}}}
+    # 33 ms a device over the three batches
+    assert read(run) == pytest.approx(11.0)
+    run.trace = {"scopes": {"/device:TPU:0": {"": 0.002}}}
+    assert read(run) is None
+    run.trace = None
+    assert read(run) is None

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from benchmark.reference import exact_knn, pair_distances
 
@@ -39,3 +40,36 @@ def test_lower_precision_reads_coarser():
     err_high = np.abs(high - exact).max()
     err_one = np.abs(one - exact).max()
     assert 0 < err_high < err_one
+
+
+def _sharded(X, shards=4):
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:shards]), ("data",))
+    Xs = jax.device_put(X, NamedSharding(mesh, P("data")))
+    assert len({s.device for s in Xs.addressable_shards}) == shards
+    return Xs
+
+
+@pytest.mark.parametrize("precision", ["highest", "high", "default"])
+def test_sharded_corpus_reads_the_one_device_answer(precision):
+    """Ties included: the data repeats rows, so many distances tie."""
+    X, Q = _data(4, n=4000)
+    X[2000:2500] = X[:500]
+    one = exact_knn(jnp.asarray(X), Q, 10, tile=500, q_tile=32,
+                    precision=precision)
+    four = exact_knn(_sharded(X), Q, 10, tile=500, q_tile=32,
+                     precision=precision)
+    np.testing.assert_array_equal(four[1], one[1])
+    np.testing.assert_array_equal(four[0], one[0])
+    assert (one[1] >= 2000).any() and (one[1] < 2000).any()
+
+
+def test_sharded_pair_distances_equal_the_one_device_ones():
+    X, Q = _data(5, n=4000)
+    ids = np.random.default_rng(6).integers(-5, len(X) + 5, size=(len(Q), 7))
+    one = pair_distances(jnp.asarray(X), Q, ids, q_tile=16)
+    four = pair_distances(_sharded(X), Q, ids, q_tile=16)
+    assert four.dtype == one.dtype == np.float32
+    np.testing.assert_array_equal(four, one)
